@@ -14,7 +14,7 @@ while wave t's decisions are in flight, after a bucket-ladder
 sequential run and the batch replay — overlap moves the wall clock,
 never the decisions.
 
-    REPRO_KERNEL_INTERPRET=auto PYTHONPATH=src python examples/live_gateway.py [--pipeline]
+    PYTHONPATH=src python examples/live_gateway.py [--pipeline]
 """
 
 import sys
@@ -110,4 +110,6 @@ def main(pipeline: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(pipeline="--pipeline" in sys.argv[1:])

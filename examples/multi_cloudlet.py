@@ -65,4 +65,6 @@ def main(out_csv: str = "multi_cloudlet.csv"):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main(*sys.argv[1:])

@@ -62,6 +62,8 @@ def composed_on_tiled_engine():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     tour_scenarios()
     batched_sweep()
     chunked_engine()

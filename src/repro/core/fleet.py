@@ -24,6 +24,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import baselines as bl
 from repro.core import onalgo
 from repro.core.onalgo import OnAlgoParams, StepRule
+from repro.parallel.mesh import fleet_mesh
 from repro.topology import Topology, validate_topology
 
 
@@ -137,8 +138,10 @@ def simulate(trace: Trace,
 
     ``collect_decisions`` adds the realized per-device decision matrices
     to the series — ``offload_mask`` / ``admit_mask``, (T, N) bool —
-    the ground truth the live gateway's replay is checked against
-    (O(T * N) memory: a test/diagnostics flag, not a fleet-scale one).
+    the ground truth the live gateway's replay is checked against, and
+    for OnAlgo ``offload_margin`` (T, N) fp32, each decision's
+    ``onalgo.decision_margin`` (O(T * N) memory: a test/diagnostics
+    flag, not a fleet-scale one).
     """
     o_tab, h_tab, w_tab = tables
     T, N = trace.j_idx.shape
@@ -196,6 +199,10 @@ def simulate(trace: Trace,
                          else topo_k.assoc)
 
         mu_k = None
+        if algo == "onalgo" and collect_decisions:
+            margin = onalgo.decision_margin(
+                state, o_now, h_now, w_now, params,
+                assoc=None if topo_k is None else assoc_now)
         if algo == "onalgo":
             if topo_k is None:
                 state, offload = onalgo.step(state, j, o_now, h_now, w_now,
@@ -258,6 +265,8 @@ def simulate(trace: Trace,
         if collect_decisions:
             out["offload_mask"] = offload
             out["admit_mask"] = admitted
+            if algo == "onalgo":
+                out["offload_margin"] = margin
         if topology is not None:
             out["mu_k"] = (mu_k if mu_k is not None
                            else jnp.full((topology.K,), mu))
@@ -322,7 +331,7 @@ def _series_from_offloads(j_seq, off, tables, params, mu_seq, lnorm,
                           enforce_slot_capacity: bool,
                           smallest_first: bool = False,
                           topology: Optional[Topology] = None,
-                          t0: int = 0):
+                          t0: int = 0, collect_decisions: bool = False):
     """Whole-horizon series assembly shared by the offload-matrix engines.
 
     The chunked/tiled kernels and the sharded scan produce the realized
@@ -337,6 +346,8 @@ def _series_from_offloads(j_seq, off, tables, params, mu_seq, lnorm,
     ids — ``t0`` locates this span inside a time-varying map) and adds
     the ``mu_k`` series; ``mu_seq`` may then be (T, K) per-cloudlet duals
     (the scalar ``mu`` series becomes their cloudlet mean).
+    ``collect_decisions`` adds the ``offload_mask`` / ``admit_mask``
+    matrices, as in ``simulate``.
     """
     o_tab, h_tab, w_tab = tables
     if overlay is None:
@@ -359,8 +370,12 @@ def _series_from_offloads(j_seq, off, tables, params, mu_seq, lnorm,
                 admitted = jax.vmap(lambda o_, h_: admit(o_, h_, None))(
                     off, h_seq)
             else:
+                # one slot at a time: batched over slots, the argsort and
+                # the scatter back compile for minutes at fleet scale on
+                # the TPU; one slot's program compiles in seconds
                 a_seq = topology.assoc_at(t0, off.shape[0])
-                admitted = jax.vmap(admit)(off, h_seq, a_seq)
+                admitted = jax.lax.map(lambda x: admit(*x),
+                                       (off, h_seq, a_seq))
     else:
         admitted = off
     adm_f = admitted.astype(jnp.float32)
@@ -387,6 +402,9 @@ def _series_from_offloads(j_seq, off, tables, params, mu_seq, lnorm,
         series["correct"] = jnp.sum(
             jnp.where(admitted, overlay.correct_cloud,
                       overlay.correct_local) * task_f, axis=1)
+    if collect_decisions:
+        series["offload_mask"] = off
+        series["admit_mask"] = admitted
     return series
 
 
@@ -454,8 +472,23 @@ def _onalgo_tail(state, j_tail, overlay_tail: Optional[RawOverlay],
     return state, off_t, mu_t, ln_t
 
 
+def _rollout_kernel(N: int, M: int, block_n: Optional[int]):
+    """The fused rollout kernel for N devices over M states: the
+    device-tiled kernel with ``block_n`` devices per tile when given,
+    else whichever of whole-fleet / tiled the fleet's size calls for
+    (``kernels.onalgo_step.rollout_block_n``)."""
+    from repro.kernels import ops as kops
+    from repro.kernels.onalgo_step import rollout_block_n
+
+    if block_n is None:
+        block_n = rollout_block_n(N, M)
+    return (kops.onalgo_chunked if block_n is None
+            else partial(kops.onalgo_tiled, block_n=block_n))
+
+
 @partial(jax.jit, static_argnames=("chunk", "block_n", "algo",
-                                   "enforce_slot_capacity"))
+                                   "enforce_slot_capacity",
+                                   "collect_decisions"))
 def simulate_chunked(trace: Trace, tables, params: OnAlgoParams,
                      rule: StepRule, chunk: int = 8,
                      block_n: Optional[int] = None,
@@ -463,7 +496,9 @@ def simulate_chunked(trace: Trace, tables, params: OnAlgoParams,
                      overlay: Optional[RawOverlay] = None,
                      enforce_slot_capacity: bool = False,
                      topology: Optional[Topology] = None,
-                     topo_binned: Optional[bool] = None):
+                     topo_binned: Optional[bool] = None,
+                     collect_decisions: bool = False,
+                     t0=0, state0: Optional[onalgo.OnAlgoState] = None):
     """OnAlgo rollout through the fused whole-simulation Pallas kernels.
 
     Equivalent to ``simulate(..., algo="onalgo")`` (same series keys, same
@@ -472,10 +507,10 @@ def simulate_chunked(trace: Trace, tables, params: OnAlgoParams,
     (see kernels/onalgo_step.py).  A non-divisible tail of ``T mod chunk``
     slots is finished by the jnp slot step.
 
-    block_n: None keeps the whole fleet's tables/state VMEM-resident (the
-      time-chunked kernel, N*M-bounded); an int routes through the
-      device-tiled kernel — block_n devices per tile, O(block_n * M) VMEM —
-      so arbitrarily large fleets run chunked too.
+    block_n: None (default) picks the kernel from N and M — the whole
+      fleet's tables/state VMEM-resident while they fit, else the
+      device-tiled kernel (O(block_n * M) VMEM, any fleet size); an int
+      forces the tiled kernel with block_n devices per tile.
     algo: ``onalgo`` (the kernels), or the service tier's stateless
       ``local`` / ``cloud`` policies (no kernel needed).
     overlay: optional service-tier RawOverlay — raw per-slot values drive
@@ -496,9 +531,13 @@ def simulate_chunked(trace: Trace, tables, params: OnAlgoParams,
       memory and an MXU contraction instead of an (N, K_pad) one-hot
       mask.  None (default) auto-selects by K; ``fleet.autotune`` probes
       both on large-K topologies.  Ignored without a topology.
+    collect_decisions: add the realized ``offload_mask`` / ``admit_mask``
+      (T, N) matrices to the series, as ``simulate`` does.
+    t0 / state0: resume mid-horizon — the trace (and overlay, topology)
+      cover slots [t0, t0 + T), rolled from ``state0`` (an OnAlgoState
+      whose ``rho.t`` is t0; ``t0`` may be traced).  Bit-identical to the
+      same span of one run from slot 0.
     """
-    from repro.kernels import ops as kops
-
     o_tab, h_tab, w_tab = tables
     T, N = trace.j_idx.shape
     M = o_tab.shape[-1]
@@ -511,7 +550,8 @@ def simulate_chunked(trace: Trace, tables, params: OnAlgoParams,
         series = _series_from_offloads(j_seq, off, tables, params, mu_seq,
                                        lnorm, overlay,
                                        enforce_slot_capacity,
-                                       topology=topology)
+                                       topology=topology,
+                                       collect_decisions=collect_decisions)
         return series, final
     if algo != "onalgo":
         raise ValueError("the chunked engine rolls OnAlgo (plus the "
@@ -528,13 +568,12 @@ def simulate_chunked(trace: Trace, tables, params: OnAlgoParams,
         topo_kw = dict(H_k=H_k_eff, topo_binned=topo_binned)
 
     T_main = (T // chunk) * chunk
-    lam = jnp.zeros((N,), jnp.float32)
-    mu = (jnp.float32(0.0) if topo_k is None
-          else jnp.zeros((topo_k.K,), jnp.float32))
-    counts = jnp.zeros((N, M), jnp.float32)
+    if state0 is None:
+        state0 = onalgo.init_state(
+            N, M, K=None if topo_k is None else topo_k.K)
+    lam, mu, counts = state0.lam, state0.mu, state0.rho.counts
     if T_main:
-        kern = (kops.onalgo_chunked if block_n is None
-                else partial(kops.onalgo_tiled, block_n=block_n))
+        kern = _rollout_kernel(N, M, block_n)
         sv_main = (None if slot_values is None
                    else tuple(sv[:T_main] for sv in slot_values))
         if topo_k is not None:  # static maps stay (N,): no (T, N) bcast
@@ -542,7 +581,8 @@ def simulate_chunked(trace: Trace, tables, params: OnAlgoParams,
                                 if topo_k.time_varying else topo_k.assoc)
         off, mu_seq, lnorm, lam, mu, counts = kern(
             j_seq[:T_main], lam, mu, counts, o_s, h_s, w_tab, B_eff, H_eff,
-            rule.a, rule.beta, chunk=chunk, slot_values=sv_main, **topo_kw)
+            rule.a, rule.beta, chunk=chunk, t0=t0, slot_values=sv_main,
+            **topo_kw)
     else:  # whole horizon shorter than one chunk: jnp tail does it all
         off = jnp.zeros((0, N), bool)
         mu_seq = jnp.zeros((0,) if topo_k is None else (0, topo_k.K),
@@ -553,7 +593,7 @@ def simulate_chunked(trace: Trace, tables, params: OnAlgoParams,
         state = onalgo.OnAlgoState(
             lam=lam, mu=mu,
             rho=onalgo.RhoEstimator(counts=counts,
-                                    t=jnp.int32(T_main)))
+                                    t=jnp.int32(t0 + T_main)))
         overlay_tail = None if overlay is None else RawOverlay(
             o=overlay.o[T_main:], h=overlay.h[T_main:],
             w=overlay.w[T_main:],
@@ -572,10 +612,11 @@ def simulate_chunked(trace: Trace, tables, params: OnAlgoParams,
 
     series = _series_from_offloads(j_seq, off, tables, params, mu_seq,
                                    lnorm, overlay, enforce_slot_capacity,
-                                   topology=topology)
+                                   topology=topology,
+                                   collect_decisions=collect_decisions)
     final = onalgo.OnAlgoState(
         lam=lam, mu=mu,
-        rho=onalgo.RhoEstimator(counts=counts, t=jnp.int32(T)))
+        rho=onalgo.RhoEstimator(counts=counts, t=jnp.int32(t0 + T)))
     return series, final
 
 
@@ -692,8 +733,6 @@ def _pipelined_slab_step(carry, t0, t_buf, tables, params, rule, topology,
     The host loop never touches the outputs, so slab t+1's launch is
     enqueued while slab t is still executing (double-buffered dispatch).
     """
-    from repro.kernels import ops as kops
-
     lam, mu, counts, bufs = carry
     j_slab, overlay = src(t0, L)
     o_tab, h_tab, w_tab = tables
@@ -709,8 +748,7 @@ def _pipelined_slab_step(carry, t0, t_buf, tables, params, rule, topology,
         topo_kw = dict(assoc=(topo_k.assoc_at(t0, L)
                               if topo_k.time_varying else topo_k.assoc),
                        H_k=H_k_eff, topo_binned=topo_binned)
-    kern = (kops.onalgo_chunked if block_n is None
-            else partial(kops.onalgo_tiled, block_n=block_n))
+    kern = _rollout_kernel(j_slab.shape[1], counts.shape[-1], block_n)
     off, mu_seq, lnorm, lam, mu, counts = kern(
         j_slab, lam, mu, counts, o_s, h_s, w_tab, B_eff, H_eff,
         rule.a, rule.beta, chunk=chunk, t0=t0, slot_values=sv, **topo_kw)
@@ -759,7 +797,8 @@ def simulate_chunked_stream(source, T: int, N: int, tables,
     slab on device, run the fused Pallas kernel on it (resuming via its
     traced ``t0`` — one compile for every slab), fold the slab's
     accounting, drop the slab.  Peak device memory is O(slab * N) +
-    O(N * M) state (or O(block_n * M) tiles with ``block_n``),
+    O(N * M) state (streamed through O(block_n * M) VMEM tiles at
+    fleet scale),
     independent of T * N; only the O(T) per-slot series survive.
 
     Metrics are identical to materializing the workload and calling
@@ -790,8 +829,6 @@ def simulate_chunked_stream(source, T: int, N: int, tables,
 
     Returns the standard ``(series, final_state)`` contract.
     """
-    from repro.kernels import ops as kops
-
     o_tab, h_tab, w_tab = tables
     M = o_tab.shape[-1]
     if slab is None:
@@ -874,8 +911,7 @@ def simulate_chunked_stream(source, T: int, N: int, tables,
 
     o_s, h_s, B_eff, H_eff = onalgo.precondition_tables(o_tab, h_tab,
                                                         params)
-    kern = (kops.onalgo_chunked if block_n is None
-            else partial(kops.onalgo_tiled, block_n=block_n))
+    kern = _rollout_kernel(N, M, block_n)
     if topo_k is not None:
         H_k_eff = (topo_k.H_k / params.H if params.precondition
                    else topo_k.H_k)
@@ -967,7 +1003,7 @@ def simulate_sharded(trace: Trace, tables, params: OnAlgoParams,
         raise ValueError("the sharded engine rolls OnAlgo (plus the "
                          f"stateless local/cloud policies); got {algo!r}")
 
-    _validate_shards(N, mesh, device_axis)
+    mesh = fleet_mesh(mesh, N, device_axis)
     run = _make_sharded_run(mesh, device_axis, rule,
                             per_device_tables=o_tab.ndim == 2,
                             has_overlay=overlay is not None,
@@ -993,14 +1029,6 @@ def simulate_sharded(trace: Trace, tables, params: OnAlgoParams,
         lam=lam, mu=mu,
         rho=onalgo.RhoEstimator(counts=counts, t=jnp.int32(T)))
     return series, final
-
-
-def _validate_shards(N: int, mesh, device_axis: str):
-    n_shards = mesh.shape[device_axis]
-    if N % n_shards:
-        raise ValueError(
-            f"fleet size N={N} must be a multiple of the {device_axis!r} "
-            f"axis shard count ({n_shards})")
 
 
 def _sharded_slot(o_t, h_t, w_t, p_local, rule, device_axis, *,
@@ -1062,8 +1090,6 @@ def _make_sharded_run(mesh, device_axis: str, rule: StepRule, *,
     the per-slot collective is the psum of each shard's (K,) segment
     partials.
     """
-    from repro.parallel.compat import shard_map
-
     tab_spec = P(device_axis, None) if per_device_tables else P(None)
     seq_spec = P(None, device_axis)
     ov_specs = (seq_spec,) * 3 if has_overlay else ()
@@ -1073,7 +1099,7 @@ def _make_sharded_run(mesh, device_axis: str, rule: StepRule, *,
         assoc_spec = seq_spec if topo_tv else P(device_axis)
         topo_specs = (assoc_spec, P())
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(seq_spec, tab_spec, tab_spec, tab_spec,
                        P(device_axis), P(), P(device_axis), P(),
                        P(device_axis, None), P()) + ov_specs + topo_specs,
@@ -1117,8 +1143,6 @@ def _make_sharded_stream_run(mesh, device_axis: str, rule: StepRule,
     streams) is returned gathered so the caller's accounting post-pass
     stays engine-independent.
     """
-    from repro.parallel.compat import shard_map
-
     tab_spec = P(device_axis, None) if per_device_tables else P(None)
     seq_spec = P(None, device_axis)
     n_seq_out = 7 if has_overlay else 2  # off + j (+ 5 overlay streams)
@@ -1128,7 +1152,7 @@ def _make_sharded_stream_run(mesh, device_axis: str, rule: StepRule,
         assoc_spec = seq_spec if topo_tv else P(device_axis)
         topo_specs = (assoc_spec, P())
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(tab_spec, tab_spec, tab_spec,
                        P(device_axis), P(), P(device_axis), P(),
                        P(device_axis, None), P()) + topo_specs,
@@ -1200,7 +1224,7 @@ def simulate_sharded_stream(source, T: int, N: int, tables,
     """
     o_tab, h_tab, w_tab = tables
     M = o_tab.shape[-1]
-    _validate_shards(N, mesh, device_axis)
+    mesh = fleet_mesh(mesh, N, device_axis)
     if slab is None:
         slab = 256
     validate_topology(topology, T, N)

@@ -159,6 +159,20 @@ def decide(lam, mu, o_now, h_now, w_now, task_mask):
     return (price < w_now) & (w_now > 0) & task_mask
 
 
+def decision_margin(state: OnAlgoState, o_now, h_now, w_now,
+                    params: OnAlgoParams, assoc=None):
+    """w - (lam o + mu h) for the current slot under the duals
+    ``state`` carries into it, in the space the duals live in: the
+    quantity whose sign :func:`step`'s realized decision thresholds
+    (a device with w > 0 and a task offloads iff it is positive).
+    ``assoc`` (N,) gathers each device's cloudlet dual from a (K,) mu."""
+    if params.precondition:
+        o_now = o_now / params.B
+        h_now = h_now / params.H
+    mu_n = state.mu[assoc] if assoc is not None else state.mu
+    return w_now - (state.lam * o_now + mu_n * h_now)
+
+
 def constraint_slacks(y_pol, rho, o_tab, h_tab, params: OnAlgoParams,
                       axis_name: Optional[str] = None):
     """g_t(y): per-device power slack (N,) and global capacity slack ().

@@ -1,44 +1,28 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Interpret mode is auto-detected: on a TPU runtime the kernels lower
-natively; anywhere else (CPU build box, CI) they execute through the
-Pallas interpreter for correctness validation.  Override with
-``REPRO_KERNEL_INTERPRET=0`` (force native) or ``=1`` (force interpret);
-the default ``auto`` asks the JAX backend.
+The kernels lower natively on a TPU and run through the Pallas
+interpreter on the CPU (tests, a build box without the chip); the
+platform decides, and no other platform is served.
 """
 
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
 
 
-_INTERPRET = None
-
-
 def interpret_mode() -> bool:
-    """True when the Pallas kernels should run through the interpreter.
-
-    Evaluated lazily on first use: the auto branch queries
-    ``jax.default_backend()``, which initializes the JAX backend — doing
-    that at import time would pin the platform before launch/dryrun.py
-    gets to set XLA_FLAGS.
+    """True exactly when JAX's default platform is the CPU: the kernels
+    then run through the Pallas interpreter.  Asked lazily (it
+    initializes the backend); any platform but ``cpu`` or ``tpu`` raises.
     """
-    global _INTERPRET
-    if _INTERPRET is None:
-        mode = os.environ.get("REPRO_KERNEL_INTERPRET", "auto").lower()
-        if mode in ("0", "false", "native"):
-            _INTERPRET = False
-        elif mode in ("1", "true", "interpret"):
-            _INTERPRET = True
-        else:
-            try:
-                _INTERPRET = jax.default_backend() != "tpu"
-            except Exception:
-                _INTERPRET = True
-    return _INTERPRET
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas kernels run on a TPU, or interpreted on the CPU; "
+            f"JAX's platform is {platform!r}")
+    return platform == "cpu"
 
 
 @partial(jax.jit, static_argnames=())

@@ -11,19 +11,7 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 
 from __future__ import annotations
 
-import jax
-
-try:  # AxisType landed after jax 0.4.x; Auto is the pre-existing default
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-
-def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+from repro.parallel.mesh import make_mesh as _make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

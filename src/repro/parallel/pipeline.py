@@ -32,9 +32,7 @@ def pipeline_apply(stage_fn, stage_params, microbatches, mesh,
     S = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
     M = microbatches.shape[0]
 
-    from repro.parallel.compat import shard_map
-
-    @partial(shard_map, mesh=mesh, in_specs=(P(axis), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(axis), P()),
              out_specs=P(), check_vma=False)
     def run(params, x):
         local = jax.tree.map(lambda p: p[0], params)  # this stage's params

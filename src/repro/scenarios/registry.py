@@ -16,7 +16,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -125,8 +124,7 @@ def _bursty_counter(sc: Scenario) -> CompiledScenario:
     T, N = sc.T, sc.N
     p_on, p_stay, p_init = arrival_chain_probs(burst_len, mean_gap)
     u = streams.uniform_block(sc.seed, streams.STREAM_SCENARIO, T, N, 1)
-    u0 = jax.random.uniform(
-        streams.stream_key(sc.seed, streams.STREAM_ARRIVAL_INIT), (N,))
+    u0 = streams.uniform_vector(sc.seed, streams.STREAM_ARRIVAL_INIT, N)
     on = np.asarray(streams.markov_chain(u[0], u0 < p_init,
                                          jnp.float32(p_on),
                                          jnp.float32(p_stay)))

@@ -68,6 +68,7 @@ import numpy as np
 from repro.core import baselines as bl
 from repro.core import onalgo
 from repro.core.onalgo import OnAlgoParams, StepRule
+from repro.parallel.mesh import auto_axes
 from repro.serve.admission import quantize_states_device
 from repro.serve.engine import WaveBuckets
 from repro.topology import Topology, validate_topology
@@ -88,6 +89,45 @@ def default_buckets(num_devices: int, base: int = 64) -> Tuple[int, ...]:
         b *= 2
     out.append(num_devices)
     return tuple(out)
+
+
+def make_tick(N: int, space, *, topo_duals: bool, admit_topo: bool,
+              enforce: bool):
+    """The gateway's slot function ``tick(state, tables, params, rule,
+    idx, o, h, w, assoc, H_k) -> (state, off, adm)`` for an N-device
+    fleet (:class:`GatewayCore` jits it with the state donated)."""
+
+    def tick(state, tables, params, rule, idx, o, h, w, assoc, H_k):
+        # scatter the wave into fleet-shaped buffers; pad slots carry
+        # idx = N and drop.  Non-reporting devices quantize to j = 0
+        # (null state) — identical to a False arrival in the batch
+        # workload, so the slot replays bit for bit.
+        zeros = jnp.zeros((N,), jnp.float32)
+        o_f = zeros.at[idx].set(o, mode="drop")
+        h_f = zeros.at[idx].set(h, mode="drop")
+        w_f = zeros.at[idx].set(w, mode="drop")
+        task = jnp.zeros((N,), bool).at[idx].set(True, mode="drop")
+        j = quantize_states_device(space, o_f, h_f, w_f, task)
+        if topo_duals:
+            state, off = onalgo.step(state, j, o_f, h_f, w_f, task,
+                                     tables, params, rule, assoc=assoc,
+                                     H_k=H_k)
+        else:
+            state, off = onalgo.step(state, j, o_f, h_f, w_f, task,
+                                     tables, params, rule)
+        if not enforce:
+            adm = off
+        elif admit_topo:
+            adm = bl.admit_by_capacity_topo(off, h_f, assoc, H_k)
+        else:
+            adm = bl.admit_by_capacity(off, h_f, params.H)
+        # gather the wave's decisions back (pads clip to device N-1
+        # and are sliced off on the host)
+        off_r = jnp.take(off, idx, mode="clip")
+        adm_r = jnp.take(adm, idx, mode="clip")
+        return state, off_r, adm_r
+
+    return tick
 
 
 @dataclasses.dataclass
@@ -202,13 +242,17 @@ class GatewayCore:
         self._est_resolve_ms: dict = {}
         self._est_alpha = float(est_alpha)
         self._last_resolved_at = float("-inf")
-        self._mesh = mesh
+        self._mesh = None if mesh is None else auto_axes(mesh)
         self._device_axis = device_axis
         self._state = onalgo.init_state(
             self.N, self.M, K=None if self._topo_k is None else topology.K)
-        if mesh is not None:
-            self._state = _shard_state(self._state, mesh, device_axis)
-        self._tick_fn = jax.jit(self._build_tick(), donate_argnums=(0,))
+        if self._mesh is not None:
+            self._state = _shard_state(self._state, self._mesh, device_axis)
+        self._tick_fn = jax.jit(
+            make_tick(self.N, space, topo_duals=self._topo_k is not None,
+                      admit_topo=topology is not None,
+                      enforce=self.enforce_slot_capacity),
+            donate_argnums=(0,))
 
     @classmethod
     def for_service(cls, service, **kw) -> "GatewayCore":
@@ -231,44 +275,6 @@ class GatewayCore:
         return cls.for_service(service, **kw)
 
     # ------------------------------------------------------------------
-    def _build_tick(self):
-        N, space = self.N, self.space
-        topo_duals = self._topo_k is not None
-        admit_topo = self.topology is not None
-        enforce = self.enforce_slot_capacity
-
-        def tick(state, tables, params, rule, idx, o, h, w, assoc, H_k):
-            # scatter the wave into fleet-shaped buffers; pad slots carry
-            # idx = N and drop.  Non-reporting devices quantize to j = 0
-            # (null state) — identical to a False arrival in the batch
-            # workload, so the slot replays bit for bit.
-            zeros = jnp.zeros((N,), jnp.float32)
-            o_f = zeros.at[idx].set(o, mode="drop")
-            h_f = zeros.at[idx].set(h, mode="drop")
-            w_f = zeros.at[idx].set(w, mode="drop")
-            task = jnp.zeros((N,), bool).at[idx].set(True, mode="drop")
-            j = quantize_states_device(space, o_f, h_f, w_f, task)
-            if topo_duals:
-                state, off = onalgo.step(state, j, o_f, h_f, w_f, task,
-                                         tables, params, rule, assoc=assoc,
-                                         H_k=H_k)
-            else:
-                state, off = onalgo.step(state, j, o_f, h_f, w_f, task,
-                                         tables, params, rule)
-            if not enforce:
-                adm = off
-            elif admit_topo:
-                adm = bl.admit_by_capacity_topo(off, h_f, assoc, H_k)
-            else:
-                adm = bl.admit_by_capacity(off, h_f, params.H)
-            # gather the wave's decisions back (pads clip to device N-1
-            # and are sliced off on the host)
-            off_r = jnp.take(off, idx, mode="clip")
-            adm_r = jnp.take(adm, idx, mode="clip")
-            return state, off_r, adm_r
-
-        return tick
-
     def _slot_assoc(self):
         """(assoc, H_k) device args for the current slot (None without a
         topology; a time-varying map is indexed by the slot counter)."""

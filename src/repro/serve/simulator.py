@@ -22,7 +22,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -252,7 +251,8 @@ def simulate_service(sim: SimConfig, pool: PrecomputedPool,
         raise ValueError(f"unknown engine {engine!r}; "
                          "expected scan | chunked | sharded")
     if engine == "sharded" and mesh is None:
-        mesh = jax.make_mesh((len(jax.devices()),), (device_axis,))
+        from repro.parallel.mesh import make_fleet_mesh
+        mesh = make_fleet_mesh(axis=device_axis)
     validate_topology(topology, sim.T, sim.num_devices)
 
     if not materialize:

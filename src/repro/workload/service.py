@@ -78,8 +78,7 @@ def generate_service_workload(seed, T: int, N: int, pool_size: int,
     mean_gap = jnp.float32(mean_gap)
     p_on, p_stay, p_init = arrival_chain_probs(burst_len, mean_gap)
     u = streams.uniform_block(seed, streams.STREAM_SERVICE, T, N, 4)
-    u0 = jax.random.uniform(
-        streams.stream_key(seed, streams.STREAM_ARRIVAL_INIT), (N,))
+    u0 = streams.uniform_vector(seed, streams.STREAM_ARRIVAL_INIT, N)
     on = streams.markov_chain(u[0], u0 < p_init, jnp.float32(p_on),
                               jnp.float32(p_stay))
     img = streams.levels_from_uniform(u[1], pool_size)

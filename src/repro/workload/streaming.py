@@ -175,8 +175,7 @@ def lower_service_workload(seed, T: int, N: int, pool_size: int,
     p_on, p_stay, p_init = arrival_chain_probs(burst_len, mean_gap)
     p_on, p_stay = jnp.float32(p_on), jnp.float32(p_stay)
     p_change = 1.0 - jnp.float32(channel_stay)
-    u0 = jax.random.uniform(
-        streams.stream_key(seed, streams.STREAM_ARRIVAL_INIT), (N,))
+    u0 = streams.uniform_vector(seed, streams.STREAM_ARRIVAL_INIT, N)
     s0 = u0 < p_init
     n_blocks = -(-T // RB)
 

@@ -59,25 +59,43 @@ def _block_keys(seed, sid: int, n_blocks: int, b0=0):
     return fold(stream_key(seed, sid), blocks)
 
 
-def _uniform_from_counts(key, counts):
-    """Bit-exact replica of ``jax.random.uniform(key, shape)`` restricted
-    to the given threefry counters.
-
-    ``jax.random.uniform`` draws 32 random bits per element with counter
-    ``row-major position in shape`` and maps them to [0, 1) by stuffing
-    the top 23 bits into a float32 mantissa with exponent 0 (value in
-    [1, 2)) and subtracting 1.  Reproducing that pipeline on an explicit
-    counter grid lets a shard draw any *sub-rectangle* of a block's
-    uniforms — e.g. its own device columns — with values identical to
-    slicing the full draw (asserted by tests/test_workload.py, which
-    pins this against ``jax.random.uniform`` so a jax-internals change
-    cannot drift silently).
-    """
-    from jax.extend.random import threefry_2x32
-    bits = threefry_2x32(key, counts.reshape(-1))
+def _unit(bits):
+    """uint32 words -> U[0, 1) float32: the top 23 bits become a float32
+    mantissa with exponent 0 (a value in [1, 2)), minus 1."""
     f = jax.lax.bitcast_convert_type(
         (bits >> 9) | jnp.uint32(0x3F800000), jnp.float32) - 1.0
-    return jnp.maximum(f, 0.0).reshape(counts.shape)
+    return jnp.maximum(f, 0.0)
+
+
+def _uniform_pairs(key, x0, x1):
+    """U[0, 1) draws of ``key`` at explicit threefry counter pairs.
+
+    This function IS the repo's RNG contract: every stream value is drawn
+    here, from counters the caller addresses, so the bits belong to the
+    repo rather than to a JAX default (``jax.random.uniform``'s counter
+    layout depends on the ``jax_threefry_partitionable`` flag).  One
+    Threefry-2x32 hash of the pair (x0, x1) yields the draws at both
+    counters.  Every stream pairs the first half of its flattened counter
+    grid with the second — the layout of ``jax.random.uniform`` with the
+    flag off, under which the streams were defined.  A block grid's
+    outermost axis is the slot-in-block row, so row r pairs with row
+    r + ROW_BLOCK / 2 whatever the column range: a shard draws its own
+    device columns bit-identical to slicing the full-width draw
+    (tests/test_workload.py pins both facts).
+    """
+    from jax.extend.random import threefry2x32_p
+    y0, y1 = threefry2x32_p.bind(key[0], key[1], x0, x1)
+    return _unit(y0), _unit(y1)
+
+
+def uniform_vector(seed, sid: int, n: int) -> jax.Array:
+    """(n,) U[0, 1) draws of stream ``sid`` addressed by position (e.g.
+    per-device initial states); an odd n pairs its last counter with 0."""
+    half = (n + 1) // 2
+    x0 = jnp.arange(half, dtype=jnp.uint32)
+    x1 = jnp.where(x0 + half < n, x0 + half, 0).astype(jnp.uint32)
+    u0, u1 = _uniform_pairs(stream_key(seed, sid), x0, x1)
+    return jnp.concatenate([u0, u1])[:n]
 
 
 def uniform_block_range(seed, sid: int, b0, n_blocks: int, N: int,
@@ -93,29 +111,29 @@ def uniform_block_range(seed, sid: int, b0, n_blocks: int, N: int,
     on-device generation bit-equal to a whole-horizon materialization.
     ``b0`` may be traced; ``n_blocks`` must be static.
 
-    With ``n0`` / ``n_cols`` set, only device columns [n0, n0 + n_cols)
-    are generated — addressed by their *absolute* column counter, so the
-    result is bit-identical to slicing the full-width draw, from
-    O(rows * n_cols) work (the shard-local generation primitive of
+    Within a block, the value at (row r, channel c, device n) has counter
+    ``(r * channels + c) * N + n`` under the block's key.  With ``n0`` /
+    ``n_cols`` set, only device columns [n0, n0 + n_cols) are generated
+    — addressed by their *absolute* counters, so the result is
+    bit-identical to slicing the full-width draw, from O(rows * n_cols)
+    work (the shard-local generation primitive of
     ``simulate_sharded_stream``).  ``n0`` may be traced (e.g. an
     ``axis_index`` offset inside ``shard_map``); ``n_cols`` is static.
     """
     if (n0 is None) != (n_cols is None):
         raise ValueError("n0 and n_cols must be passed together")
-    keys = _block_keys(seed, sid, n_blocks, b0)
     if n_cols is None:
-        draw = jax.vmap(
-            lambda k: jax.random.uniform(k, (ROW_BLOCK, channels, N)))
-        vals = draw(keys)  # (nb, B, C, N)
-    else:
-        r = jnp.arange(ROW_BLOCK, dtype=jnp.uint32)[:, None, None]
-        c = jnp.arange(channels, dtype=jnp.uint32)[None, :, None]
-        dn = jnp.arange(n_cols, dtype=jnp.uint32)[None, None, :]
-        counts = ((r * channels + c) * jnp.uint32(N)
-                  + jnp.uint32(n0) + dn)  # absolute column addressing
-        vals = jax.vmap(lambda k: _uniform_from_counts(k, counts))(keys)
-        N = n_cols
-    return vals.reshape(n_blocks * ROW_BLOCK, channels, N).transpose(
+        n0, n_cols = 0, N
+    keys = _block_keys(seed, sid, n_blocks, b0)
+    half = ROW_BLOCK // 2
+    r = jnp.arange(half, dtype=jnp.uint32)[:, None, None]
+    c = jnp.arange(channels, dtype=jnp.uint32)[None, :, None]
+    dn = jnp.arange(n_cols, dtype=jnp.uint32)[None, None, :]
+    x0 = (r * channels + c) * jnp.uint32(N) + jnp.uint32(n0) + dn
+    x1 = x0 + jnp.uint32(half * channels * N)  # row r + ROW_BLOCK / 2
+    vals = jax.vmap(lambda k: jnp.concatenate(
+        _uniform_pairs(k, x0, x1)))(keys)  # (nb, ROW_BLOCK, C, n_cols)
+    return vals.reshape(n_blocks * ROW_BLOCK, channels, n_cols).transpose(
         1, 0, 2)
 
 
@@ -148,32 +166,20 @@ def levels_from_uniform(u: jax.Array, num_levels: int) -> jax.Array:
     return jnp.minimum(idx, num_levels - 1)
 
 
-def _compose_bool_maps(m1, m2):
-    """Composition for associative scans over {0,1}-state transition maps.
-
-    A map is a pair ``(a, b)``: the next state when the current state is
-    0 resp. 1.  ``m2 o m1`` applies m1 first — selecting m2's entry by
-    m1's output — which is associative, so a length-T chain of per-slot
-    maps reduces in O(log T) depth.
-    """
-    a1, b1 = m1
-    a2, b2 = m2
-    pick = lambda s: jnp.where(s, b2, a2)
-    return pick(a1), pick(b1)
-
-
 def markov_chain(u: jax.Array, s0: jax.Array, p_on, p_stay) -> jax.Array:
     """(T, N) bool two-state Markov chain from per-slot uniforms ``u``.
 
     OFF -> ON w.p. ``p_on``; ON stays ON w.p. ``p_stay``; ``s0`` (N,)
-    bool is the state entering slot 0's transition.  Evaluated with an
-    *associative* scan over per-slot transition maps — no per-slot host
-    loop, no sequential device scan, O(log T) depth.
+    bool is the state entering slot 0's transition.  A sequential scan
+    over the T slots (an associative scan over per-slot transition maps
+    gives the same booleans but compiles for over a minute at
+    N = 2^20 on the TPU).
     """
-    # per-slot map: (next if OFF, next if ON)
-    maps = (u < p_on, u < p_stay)
-    a, b = jax.lax.associative_scan(_compose_bool_maps, maps, axis=0)
-    return jnp.where(s0[None, :], b, a)
+    def slot(s, u_t):
+        s = jnp.where(s, u_t < p_stay, u_t < p_on)
+        return s, s
+
+    return jax.lax.scan(slot, jnp.asarray(s0, bool), u)[1]
 
 
 def hold_resample_from(change: jax.Array, candidates: jax.Array,

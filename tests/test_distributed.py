@@ -239,13 +239,12 @@ class TestShardedFleet:
             from functools import partial
             from jax.sharding import PartitionSpec as P
             from repro.launch.mesh import make_test_mesh
-            from repro.parallel.compat import shard_map
             from repro.train.compression import compressed_psum, init_residual
 
             mesh = make_test_mesh((8,), ("data",))
             g = jnp.arange(32, dtype=jnp.float32).reshape(8, 4) / 7.0
 
-            @partial(shard_map, mesh=mesh, in_specs=P("data"),
+            @partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
                      out_specs=(P("data"), P("data")), check_vma=False)
             def run(g_shard):
                 grads = {"w": g_shard[0]}

@@ -199,6 +199,47 @@ class TestOnAlgoKernel:
         # null slots never offload, whatever the raw gain says
         assert not np.asarray(out_k[0])[np.asarray(j) == 0].any()
 
+    @pytest.mark.parametrize("n_tiles", [2, 5])
+    def test_tiled_state_carry_across_tiles_and_calls(self, n_tiles):
+        """The tiled kernel keeps lam, the visit counts and the chunk's
+        decision band in HBM and streams each tile through VMEM with
+        explicit async copies (no output block is ever read back).  Over
+        n_tiles > 1, several chunks, nonzero seeds and a resumed second
+        call at a traced t0, it matches one sequential oracle call."""
+        N, M, T, chunk, block_n, t0 = 8 * n_tiles - 3, 11, 48, 8, 8, 16
+        ks = jax.random.split(jax.random.PRNGKey(n_tiles), 7)
+        j = jax.random.randint(ks[0], (T, N), 0, M)
+        o = jax.random.uniform(ks[1], (N, M))
+        h = jax.random.uniform(ks[2], (M,))
+        w = jax.random.uniform(ks[3], (M,)) - 0.2
+        B = jax.random.uniform(ks[4], (N,)) + 0.05
+        lam0 = jax.random.uniform(ks[5], (N,)) * 0.1
+        counts0 = jax.random.randint(ks[6], (N, M), 0, 3).astype(
+            jnp.float32)
+        tabs = (o, h, w, B, jnp.float32(2.0), 0.4, 0.5)
+        want = ref.onalgo_chunked_ref(j, lam0, jnp.float32(0.05), counts0,
+                                      *tabs, t0=t0)
+        half = T // 2
+        first = onalgo_tiled_pallas(j[:half], lam0, jnp.float32(0.05),
+                                    counts0, *tabs, chunk=chunk,
+                                    block_n=block_n, t0=jnp.int32(t0))
+        second = onalgo_tiled_pallas(j[half:], first[3], first[4],
+                                     first[5], *tabs, chunk=chunk,
+                                     block_n=block_n,
+                                     t0=jnp.int32(t0 + half))
+        np.testing.assert_array_equal(
+            np.concatenate([np.asarray(first[0]), np.asarray(second[0])]),
+            np.asarray(want[0]))
+        for i in (1, 2):  # mu and lam-norm series
+            np.testing.assert_allclose(
+                np.concatenate([np.asarray(first[i]),
+                                np.asarray(second[i])]),
+                np.asarray(want[i]), rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(second[3]),
+                                   np.asarray(want[3]), rtol=1e-5, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(second[5]),
+                                      np.asarray(want[5]))
+
     def test_tiled_per_device_tables(self):
         """(N, M) heterogeneous tables stream tile by tile too."""
         N, M, T = 20, 37, 48

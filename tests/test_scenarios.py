@@ -231,6 +231,65 @@ class TestChunkedKernel:
         np.testing.assert_array_equal(np.asarray(f1.rho.counts),
                                       np.asarray(f2.rho.counts))
 
+    def test_collect_decisions_matches_scan(self):
+        """simulate_chunked(collect_decisions=True) gives the scan's
+        decision matrices, and the scan's offload_margin is positive
+        exactly where a device with a task and w > 0 offloads."""
+        space = default_paper_space(num_w=4)
+        trace, _ = iid_trace(space, TraceSpec(T=64, N=20, seed=5))
+        tables = space.tables()
+        params = OnAlgoParams(B=jnp.full((20,), 0.08), H=jnp.float32(6e8))
+        s1, _ = simulate(trace, tables, params, RULE,
+                         enforce_slot_capacity=True, collect_decisions=True)
+        s2, _ = simulate_chunked(trace, tables, params, RULE, chunk=8,
+                                 block_n=8, enforce_slot_capacity=True,
+                                 collect_decisions=True)
+        for k in ("offload_mask", "admit_mask"):
+            np.testing.assert_array_equal(np.asarray(s1[k]),
+                                          np.asarray(s2[k]), err_msg=k)
+        j = np.asarray(trace.j_idx)
+        w_now = np.asarray(tables[2])[j]
+        live = (w_now > 0) & (j > 0)
+        off = np.asarray(s1["offload_mask"])
+        assert off.any() and (live & ~off).any()
+        np.testing.assert_array_equal(
+            off, (np.asarray(s1["offload_margin"]) > 0) & live)
+
+    @pytest.mark.parametrize("K", [None, 3])
+    def test_resumed_slot_walk_matches_one_run(self, K):
+        """simulate_chunked resumed one slot at a time (t0 / state0)
+        ends in the one-run state, slot by slot in its series, bit for
+        bit — with and without a time-varying topology."""
+        from repro.topology import Topology
+
+        space = default_paper_space(num_w=4)
+        T, N = 24, 20
+        trace, _ = iid_trace(space, TraceSpec(T=T, N=N, seed=3))
+        tables = space.tables()
+        params = OnAlgoParams(B=jnp.full((N,), 0.08), H=jnp.float32(6e8))
+        topo = (None if K is None else Topology.mobility_walk(
+            K, N, T, H=6e8, p_handover=0.2, seed=4, streaming=False))
+        s1, f1 = simulate_chunked(trace, tables, params, RULE, chunk=8,
+                                  block_n=8, topology=topo)
+        st, parts = None, []
+        for t in range(T):
+            tr = dataclasses.replace(
+                trace, j_idx=trace.j_idx[t:t + 1],
+                d_local=trace.d_local[t:t + 1])
+            tp = (None if topo is None else dataclasses.replace(
+                topo, assoc=topo.assoc[t:t + 1]))
+            s, st = simulate_chunked(tr, tables, params, RULE, chunk=1,
+                                     block_n=8, topology=tp, t0=t,
+                                     state0=st)
+            parts.append(s)
+        for k in s1:
+            np.testing.assert_array_equal(
+                np.concatenate([np.asarray(p[k]) for p in parts]),
+                np.asarray(s1[k]), err_msg=k)
+        for a, b in ((st.lam, f1.lam), (st.mu, f1.mu),
+                     (st.rho.counts, f1.rho.counts), (st.rho.t, f1.rho.t)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
     def test_tiled_engine_block_size_independence(self):
         """Every tile width gives the same rollout as the whole-fleet
         chunked kernel."""
@@ -441,8 +500,7 @@ class TestCatalog:
         c = compile_scenario(sc)
         p_on, p_stay, p_init = arrival_chain_probs((5, 10), 8.0)
         u = streams.uniform_block(4, streams.STREAM_SCENARIO, 300, 6, 1)
-        u0 = jax.random.uniform(
-            streams.stream_key(4, streams.STREAM_ARRIVAL_INIT), (6,))
+        u0 = streams.uniform_vector(4, streams.STREAM_ARRIVAL_INIT, 6)
         on = np.asarray(streams.markov_chain(
             u[0], u0 < p_init, jnp.float32(p_on), jnp.float32(p_stay)))
         np.testing.assert_array_equal(np.asarray(c.trace.j_idx) > 0, on)

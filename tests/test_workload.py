@@ -42,11 +42,28 @@ class TestStreams:
             r = np.corrcoef(u[0].ravel(), u[c].ravel())[0, 1]
             assert abs(r) < 0.1
 
+    def test_counter_draw_is_the_flag_off_uniform(self):
+        """The explicit-counter draw is the stream contract: it equals
+        ``jax.random.uniform`` made with ``jax_threefry_partitionable``
+        off — the layout the streams were defined under — whatever the
+        flag's default, for block grids and per-device vectors alike."""
+        key = streams._block_keys(3, 1, 1, 5)[0]
+        with jax.threefry_partitionable(False):
+            want = np.asarray(jax.random.uniform(key, (streams.ROW_BLOCK,
+                                                       2, 5)))
+            want_vec = np.asarray(jax.random.uniform(
+                streams.stream_key(0, 2), (7,)))
+        got = np.asarray(streams.uniform_block_range(3, 1, 5, 1, 5, 2))
+        np.testing.assert_array_equal(got.transpose(1, 0, 2), want)
+        for flag in (True, False):  # the draw ignores the flag
+            with jax.threefry_partitionable(flag):
+                np.testing.assert_array_equal(
+                    np.asarray(streams.uniform_vector(0, 2, 7)), want_vec)
+
     def test_column_range_bit_identical_to_full_width(self):
         """The counter-offset column draw (shard-local generation) must
         reproduce EXACTLY the corresponding columns of the full-width
-        draw — this also pins our threefry/bit-stuffing replica of
-        ``jax.random.uniform`` against jax-internals drift."""
+        draw — both go through the one explicit-counter function."""
         full = np.asarray(streams.uniform_block_range(3, 1, 2, 3, 11, 4))
         for n0, nc in ((0, 11), (0, 3), (4, 5), (10, 1)):
             cols = np.asarray(streams.uniform_block_range(
@@ -55,8 +72,15 @@ class TestStreams:
                                           err_msg=str((n0, nc)))
 
     def test_column_range_traced_offset(self):
-        """n0 may be traced (an axis_index inside shard_map)."""
-        full = np.asarray(streams.uniform_block_range(7, 2, 0, 2, 9, 2))
+        """n0 may be traced (an axis_index inside shard_map); the columns
+        equal the explicit-counter draw of the full-width grid."""
+        half = streams.ROW_BLOCK // 2
+        r, c, n = np.meshgrid(np.arange(half), np.arange(2), np.arange(9),
+                              indexing="ij")
+        x0 = jnp.asarray((r * 2 + c) * 9 + n, jnp.uint32)
+        full = np.concatenate(
+            [np.concatenate(streams._uniform_pairs(k, x0, x0 + half * 18))
+             for k in streams._block_keys(7, 2, 2)]).transpose(1, 0, 2)
         f = jax.jit(lambda n0: streams.uniform_block_range(
             7, 2, 0, 2, 9, 2, n0=n0, n_cols=3))
         np.testing.assert_array_equal(np.asarray(f(jnp.int32(4))),
