@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import spans
 from repro.core import baselines as bl
 from repro.core import onalgo
 from repro.core.onalgo import OnAlgoParams, StepRule
@@ -349,63 +350,67 @@ def _series_from_offloads(j_seq, off, tables, params, mu_seq, lnorm,
     ``collect_decisions`` adds the ``offload_mask`` / ``admit_mask``
     matrices, as in ``simulate``.
     """
-    o_tab, h_tab, w_tab = tables
-    if overlay is None:
-        lookup_t = jax.vmap(_lookup, in_axes=(None, 0))
-        o_seq = lookup_t(o_tab, j_seq)  # (T, N)
-        h_seq = lookup_t(h_tab, j_seq)
-        w_seq = lookup_t(w_tab, j_seq)
-    else:
-        o_seq, h_seq, w_seq = overlay.o, overlay.h, overlay.w
-    off_f = off.astype(jnp.float32)
-    if enforce_slot_capacity:
-        if topology is None:
-            admit = partial(bl.admit_by_capacity, H_slot=params.H,
-                            smallest_first=smallest_first)
-            admitted = jax.vmap(admit)(off, h_seq)
+    with jax.named_scope(spans.ACCOUNTING):
+        o_tab, h_tab, w_tab = tables
+        if overlay is None:
+            lookup_t = jax.vmap(_lookup, in_axes=(None, 0))
+            o_seq = lookup_t(o_tab, j_seq)  # (T, N)
+            h_seq = lookup_t(h_tab, j_seq)
+            w_seq = lookup_t(w_tab, j_seq)
         else:
-            admit = partial(bl.admit_by_capacity_topo, H_k=topology.H_k,
-                            smallest_first=smallest_first)
-            if topology.K == 1:  # assoc is irrelevant with one cloudlet
-                admitted = jax.vmap(lambda o_, h_: admit(o_, h_, None))(
-                    off, h_seq)
-            else:
-                # one slot at a time: batched over slots, the argsort and
-                # the scatter back compile for minutes at fleet scale on
-                # the TPU; one slot's program compiles in seconds
-                a_seq = topology.assoc_at(t0, off.shape[0])
-                admitted = jax.lax.map(lambda x: admit(*x),
-                                       (off, h_seq, a_seq))
-    else:
-        admitted = off
-    adm_f = admitted.astype(jnp.float32)
-    task_f = (j_seq > 0).astype(jnp.float32)
-    series = {
-        "reward": jnp.sum(w_seq * adm_f, axis=1),
-        "power": jnp.sum(o_seq * off_f, axis=1),
-        "power_per_dev": jnp.mean(o_seq * off_f, axis=1),
-        "load": jnp.sum(h_seq * adm_f, axis=1),
-        "offloads": jnp.sum(off_f, axis=1),
-        "admits": jnp.sum(adm_f, axis=1),
-        "tasks": jnp.sum(task_f, axis=1),
-        "lam_norm": lnorm,
-    }
-    if mu_seq.ndim == 2:  # (T, K) per-cloudlet duals
-        series["mu_k"] = mu_seq
-        series["mu"] = jnp.mean(mu_seq, axis=-1)
-    else:
-        series["mu"] = mu_seq
-        if topology is not None:
-            series["mu_k"] = jnp.broadcast_to(
-                mu_seq[:, None], (mu_seq.shape[0], topology.K))
-    if overlay is not None:
-        series["correct"] = jnp.sum(
-            jnp.where(admitted, overlay.correct_cloud,
-                      overlay.correct_local) * task_f, axis=1)
-    if collect_decisions:
-        series["offload_mask"] = off
-        series["admit_mask"] = admitted
-    return series
+            o_seq, h_seq, w_seq = overlay.o, overlay.h, overlay.w
+        off_f = off.astype(jnp.float32)
+        if enforce_slot_capacity:
+            with jax.named_scope(spans.ADMISSION):
+                if topology is None:
+                    admit = partial(bl.admit_by_capacity, H_slot=params.H,
+                                    smallest_first=smallest_first)
+                    admitted = jax.vmap(admit)(off, h_seq)
+                else:
+                    admit = partial(bl.admit_by_capacity_topo,
+                                    H_k=topology.H_k,
+                                    smallest_first=smallest_first)
+                    if topology.K == 1:  # assoc is irrelevant, one cloudlet
+                        admitted = jax.vmap(
+                            lambda o_, h_: admit(o_, h_, None))(off, h_seq)
+                    else:
+                        # one slot at a time: batched over slots, the
+                        # argsort and the scatter back compile for
+                        # minutes at fleet scale on the TPU; one slot's
+                        # program compiles in seconds
+                        a_seq = topology.assoc_at(t0, off.shape[0])
+                        admitted = jax.lax.map(lambda x: admit(*x),
+                                               (off, h_seq, a_seq))
+        else:
+            admitted = off
+        adm_f = admitted.astype(jnp.float32)
+        task_f = (j_seq > 0).astype(jnp.float32)
+        series = {
+            "reward": jnp.sum(w_seq * adm_f, axis=1),
+            "power": jnp.sum(o_seq * off_f, axis=1),
+            "power_per_dev": jnp.mean(o_seq * off_f, axis=1),
+            "load": jnp.sum(h_seq * adm_f, axis=1),
+            "offloads": jnp.sum(off_f, axis=1),
+            "admits": jnp.sum(adm_f, axis=1),
+            "tasks": jnp.sum(task_f, axis=1),
+            "lam_norm": lnorm,
+        }
+        if mu_seq.ndim == 2:  # (T, K) per-cloudlet duals
+            series["mu_k"] = mu_seq
+            series["mu"] = jnp.mean(mu_seq, axis=-1)
+        else:
+            series["mu"] = mu_seq
+            if topology is not None:
+                series["mu_k"] = jnp.broadcast_to(
+                    mu_seq[:, None], (mu_seq.shape[0], topology.K))
+        if overlay is not None:
+            series["correct"] = jnp.sum(
+                jnp.where(admitted, overlay.correct_cloud,
+                          overlay.correct_local) * task_f, axis=1)
+        if collect_decisions:
+            series["offload_mask"] = off
+            series["admit_mask"] = admitted
+        return series
 
 
 def _trivial_policy_rollout(j_seq, algo: str):
@@ -689,9 +694,10 @@ def _write_series(bufs: dict, part: dict, at) -> dict:
     """Write one slab's series ``part`` into the run buffers at ``at``
     (traced offset).  Works traced (inside the fused step) and eager
     (folding the jnp tail after the loop)."""
-    return {k: jax.lax.dynamic_update_slice_in_dim(
-        bufs[k], part[k].astype(bufs[k].dtype), at, axis=0)
-        for k in bufs}
+    with jax.named_scope(spans.ACCOUNTING):
+        return {k: jax.lax.dynamic_update_slice_in_dim(
+            bufs[k], part[k].astype(bufs[k].dtype), at, axis=0)
+            for k in bufs}
 
 
 @partial(jax.jit, donate_argnums=(0,), static_argnames=("enforce",))
@@ -736,10 +742,11 @@ def _pipelined_slab_step(carry, t0, t_buf, tables, params, rule, topology,
     lam, mu, counts, bufs = carry
     j_slab, overlay = src(t0, L)
     o_tab, h_tab, w_tab = tables
-    o_s, h_s, B_eff, H_eff = onalgo.precondition_tables(o_tab, h_tab,
-                                                        params)
-    sv = (None if overlay is None
-          else _overlay_slot_values(overlay, params))
+    with jax.named_scope(spans.ROLLOUT):  # the kernel's operands
+        o_s, h_s, B_eff, H_eff = onalgo.precondition_tables(o_tab, h_tab,
+                                                            params)
+        sv = (None if overlay is None
+              else _overlay_slot_values(overlay, params))
     topo_k = _topo_duals(topology)
     topo_kw = {}
     if topo_k is not None:
@@ -829,132 +836,143 @@ def simulate_chunked_stream(source, T: int, N: int, tables,
 
     Returns the standard ``(series, final_state)`` contract.
     """
-    o_tab, h_tab, w_tab = tables
-    M = o_tab.shape[-1]
-    if slab is None:
-        slab = chunk * 16
-    if slab % chunk:
-        raise ValueError(f"slab={slab} must be a multiple of chunk={chunk}")
-    validate_topology(topology, T, N)
-    topo_k = _topo_duals(topology)
-    start = int(t0)
-    if not 0 <= start < max(T, 1):
-        raise ValueError(f"resume t0={start} outside horizon [0, {T})")
-    if pipelined is None:
-        pipelined = N >= _PIPELINE_AUTO_N
-
-    if algo in ("local", "cloud"):
+    span = jax.profiler.TraceAnnotation
+    with span(spans.STREAM_PREPARE):
+        o_tab, h_tab, w_tab = tables
+        M = o_tab.shape[-1]
+        if slab is None:
+            slab = chunk * 16
+        if slab % chunk:
+            raise ValueError(f"slab={slab} must be a multiple of "
+                             f"chunk={chunk}")
+        validate_topology(topology, T, N)
+        topo_k = _topo_duals(topology)
+        start = int(t0)
+        if not 0 <= start < max(T, 1):
+            raise ValueError(f"resume t0={start} outside horizon [0, {T})")
+        if pipelined is None:
+            pipelined = N >= _PIPELINE_AUTO_N
+        stateless = algo in ("local", "cloud")
+        if not stateless and algo != "onalgo":
+            raise ValueError("the chunked streaming engine rolls OnAlgo "
+                             "(plus the stateless local/cloud policies); "
+                             f"got {algo!r}")
+        if not stateless:
+            lam, mu, counts = _stream_state0(state0, N, M, topo_k)
+            T_main = start + ((T - start) // chunk) * chunk
+            if pipelined:
+                from repro.workload.streams import ROW_BLOCK
+                use_aligned = (source_aligned is not None
+                               and start % ROW_BLOCK == 0
+                               and slab % ROW_BLOCK == 0)
+                src = _StaticSource(source_aligned if use_aligned
+                                    else source)
+                probe_L = min(slab, T - start)
+                has_overlay = jax.eval_shape(
+                    lambda t: source(t, probe_L),
+                    jax.ShapeDtypeStruct((), jnp.int32))[1] is not None
+                bufs = _stream_series_buffers(T - start, topology,
+                                              has_overlay)
+            else:
+                o_s, h_s, B_eff, H_eff = onalgo.precondition_tables(
+                    o_tab, h_tab, params)
+                kern = _rollout_kernel(N, M, block_n)
+                if topo_k is not None:
+                    H_k_eff = (topo_k.H_k / params.H if params.precondition
+                               else topo_k.H_k)
+    if stateless:
         return _stream_trivial(source, T, N, slab, tables, params, algo,
                                enforce_slot_capacity, topology=topology,
                                start=start)
-    if algo != "onalgo":
-        raise ValueError("the chunked streaming engine rolls OnAlgo (plus "
-                         "the stateless local/cloud policies); got "
-                         f"{algo!r}")
-
-    if state0 is not None:
-        # copies: the pipelined steps donate their carry, and the caller
-        # keeps its resume state
-        lam = jnp.array(state0.lam, jnp.float32)
-        mu = jnp.array(state0.mu, jnp.float32)
-        counts = jnp.array(state0.rho.counts, jnp.float32)
-    else:
-        lam = jnp.zeros((N,), jnp.float32)
-        mu = (jnp.float32(0.0) if topo_k is None
-              else jnp.zeros((topo_k.K,), jnp.float32))
-        counts = jnp.zeros((N, M), jnp.float32)
-    T_main = start + ((T - start) // chunk) * chunk
 
     if pipelined:
-        from repro.workload.streams import ROW_BLOCK
-        use_aligned = (source_aligned is not None
-                       and start % ROW_BLOCK == 0
-                       and slab % ROW_BLOCK == 0)
-        src = _StaticSource(source_aligned if use_aligned else source)
-        probe_L = min(slab, T - start)
-        has_overlay = jax.eval_shape(
-            lambda t: source(t, probe_L),
-            jax.ShapeDtypeStruct((), jnp.int32))[1] is not None
-        bufs = _stream_series_buffers(T - start, topology, has_overlay)
         carry = (lam, mu, counts, bufs)
         for s0 in range(start, T_main, slab):
             L = min(slab, T_main - s0)
-            carry = _pipelined_slab_step(
-                carry, jnp.int32(s0), jnp.int32(s0 - start), tables,
-                params, rule, topology, src=src, L=L, chunk=chunk,
-                block_n=block_n,
-                enforce_slot_capacity=enforce_slot_capacity,
-                topo_binned=topo_binned)
+            with span(spans.STREAM_DISPATCH, t0=s0):
+                carry = _pipelined_slab_step(
+                    carry, jnp.int32(s0), jnp.int32(s0 - start), tables,
+                    params, rule, topology, src=src, L=L, chunk=chunk,
+                    block_n=block_n,
+                    enforce_slot_capacity=enforce_slot_capacity,
+                    topo_binned=topo_binned)
         lam, mu, counts, bufs = carry
         if T_main < T:  # finish the tail with the jnp slot step
-            j_tail, overlay_t = source(T_main, T - T_main)
-            state = onalgo.OnAlgoState(
-                lam=lam, mu=mu,
-                rho=onalgo.RhoEstimator(counts=counts,
-                                        t=jnp.int32(T_main)))
-            assoc_tail = (topo_k.assoc_at(T_main, T - T_main)
-                          if topo_k is not None and topo_k.time_varying
-                          else None)
-            state, off_t, mu_t, ln_t = _onalgo_tail(
-                state, j_tail, overlay_t, tables, params, rule,
-                topo_k=topo_k, assoc_tail=assoc_tail)
-            part = _series_from_offloads(j_tail, off_t, tables, params,
-                                         mu_t, ln_t, overlay_t,
-                                         enforce_slot_capacity,
-                                         topology=topology, t0=T_main)
-            bufs = _write_series(bufs, part, T_main - start)
+            with span(spans.STREAM_TAIL):
+                state, part = _stream_tail(
+                    source, T_main, T, lam, mu, counts, tables, params,
+                    rule, topology, enforce_slot_capacity)
+                bufs = _write_series(bufs, part, T_main - start)
             lam, mu, counts = state.lam, state.mu, state.rho.counts
         final = onalgo.OnAlgoState(
             lam=lam, mu=mu,
             rho=onalgo.RhoEstimator(counts=counts, t=jnp.int32(T)))
         return bufs, final
 
-    o_s, h_s, B_eff, H_eff = onalgo.precondition_tables(o_tab, h_tab,
-                                                        params)
-    kern = _rollout_kernel(N, M, block_n)
-    if topo_k is not None:
-        H_k_eff = (topo_k.H_k / params.H if params.precondition
-                   else topo_k.H_k)
     parts = []
     for s0 in range(start, T_main, slab):
         L = min(slab, T_main - s0)
-        j_slab, overlay = source(s0, L)
-        sv = (None if overlay is None
-              else _overlay_slot_values(overlay, params))
-        topo_kw = ({} if topo_k is None
-                   else dict(assoc=(topo_k.assoc_at(s0, L)
-                                    if topo_k.time_varying
-                                    else topo_k.assoc), H_k=H_k_eff,
-                             topo_binned=topo_binned))
-        off, mu_seq, lnorm, lam, mu, counts = kern(
-            j_slab, lam, mu, counts, o_s, h_s, w_tab, B_eff, H_eff,
-            rule.a, rule.beta, chunk=chunk, t0=jnp.int32(s0),
-            slot_values=sv, **topo_kw)
-        parts.append(_series_from_offloads(j_slab, off, tables, params,
-                                           mu_seq, lnorm, overlay,
-                                           enforce_slot_capacity,
-                                           topology=topology, t0=s0))
+        with span(spans.STREAM_DISPATCH, t0=s0):
+            j_slab, overlay = source(s0, L)
+            sv = (None if overlay is None
+                  else _overlay_slot_values(overlay, params))
+            topo_kw = ({} if topo_k is None
+                       else dict(assoc=(topo_k.assoc_at(s0, L)
+                                        if topo_k.time_varying
+                                        else topo_k.assoc), H_k=H_k_eff,
+                                 topo_binned=topo_binned))
+            off, mu_seq, lnorm, lam, mu, counts = kern(
+                j_slab, lam, mu, counts, o_s, h_s, w_tab, B_eff, H_eff,
+                rule.a, rule.beta, chunk=chunk, t0=jnp.int32(s0),
+                slot_values=sv, **topo_kw)
+            parts.append(_series_from_offloads(
+                j_slab, off, tables, params, mu_seq, lnorm, overlay,
+                enforce_slot_capacity, topology=topology, t0=s0))
     if T_main < T:  # finish the tail with the jnp slot step
-        j_tail, overlay_t = source(T_main, T - T_main)
-        state = onalgo.OnAlgoState(
-            lam=lam, mu=mu,
-            rho=onalgo.RhoEstimator(counts=counts, t=jnp.int32(T_main)))
-        assoc_tail = (topo_k.assoc_at(T_main, T - T_main)
-                      if topo_k is not None and topo_k.time_varying
-                      else None)
-        state, off_t, mu_t, ln_t = _onalgo_tail(state, j_tail, overlay_t,
-                                                tables, params, rule,
-                                                topo_k=topo_k,
-                                                assoc_tail=assoc_tail)
-        parts.append(_series_from_offloads(j_tail, off_t, tables, params,
-                                           mu_t, ln_t, overlay_t,
-                                           enforce_slot_capacity,
-                                           topology=topology, t0=T_main))
+        with span(spans.STREAM_TAIL):
+            state, part = _stream_tail(source, T_main, T, lam, mu, counts,
+                                       tables, params, rule, topology,
+                                       enforce_slot_capacity)
+        parts.append(part)
         lam, mu, counts = state.lam, state.mu, state.rho.counts
     final = onalgo.OnAlgoState(
         lam=lam, mu=mu,
         rho=onalgo.RhoEstimator(counts=counts, t=jnp.int32(T)))
     return _cat_series(parts), final
+
+
+def _stream_state0(state0, N: int, M: int, topo_k):
+    """The (lam, mu, counts) a streaming walk starts from: copies of
+    ``state0`` (the pipelined steps donate their carry, and the caller
+    keeps its resume state), or the zero state."""
+    if state0 is not None:
+        return (jnp.array(state0.lam, jnp.float32),
+                jnp.array(state0.mu, jnp.float32),
+                jnp.array(state0.rho.counts, jnp.float32))
+    mu = (jnp.float32(0.0) if topo_k is None
+          else jnp.zeros((topo_k.K,), jnp.float32))
+    return (jnp.zeros((N,), jnp.float32), mu,
+            jnp.zeros((N, M), jnp.float32))
+
+
+def _stream_tail(source, T_main: int, T: int, lam, mu, counts, tables,
+                 params, rule, topology, enforce_slot_capacity):
+    """Slots [T_main, T) of a streaming walk, shorter than a chunk, with
+    the jnp slot step.  Returns (final state, the slots' series)."""
+    topo_k = _topo_duals(topology)
+    j_tail, overlay_t = source(T_main, T - T_main)
+    state = onalgo.OnAlgoState(
+        lam=lam, mu=mu,
+        rho=onalgo.RhoEstimator(counts=counts, t=jnp.int32(T_main)))
+    assoc_tail = (topo_k.assoc_at(T_main, T - T_main)
+                  if topo_k is not None and topo_k.time_varying else None)
+    state, off_t, mu_t, ln_t = _onalgo_tail(
+        state, j_tail, overlay_t, tables, params, rule, topo_k=topo_k,
+        assoc_tail=assoc_tail)
+    part = _series_from_offloads(j_tail, off_t, tables, params, mu_t, ln_t,
+                                 overlay_t, enforce_slot_capacity,
+                                 topology=topology, t0=T_main)
+    return state, part
 
 
 def simulate_sharded(trace: Trace, tables, params: OnAlgoParams,
@@ -1222,30 +1240,38 @@ def simulate_sharded_stream(source, T: int, N: int, tables,
     stays its own launch — both walk modes run the same executable, so
     pipelined is bit-identical to the sequential walk by construction.
     """
-    o_tab, h_tab, w_tab = tables
-    M = o_tab.shape[-1]
-    mesh = fleet_mesh(mesh, N, device_axis)
-    if slab is None:
-        slab = 256
-    validate_topology(topology, T, N)
-    topo_k = _topo_duals(topology)
-    topo_static = (None if topo_k is None
-                   else (topo_k.K, topo_k.time_varying))
-    if pipelined is None:
-        pipelined = N >= _PIPELINE_AUTO_N
-
-    if algo in ("local", "cloud"):
+    span = jax.profiler.TraceAnnotation
+    with span(spans.STREAM_PREPARE):
+        o_tab, h_tab, w_tab = tables
+        M = o_tab.shape[-1]
+        mesh = fleet_mesh(mesh, N, device_axis)
+        if slab is None:
+            slab = 256
+        validate_topology(topology, T, N)
+        topo_k = _topo_duals(topology)
+        topo_static = (None if topo_k is None
+                       else (topo_k.K, topo_k.time_varying))
+        if pipelined is None:
+            pipelined = N >= _PIPELINE_AUTO_N
+        stateless = algo in ("local", "cloud")
+        if not stateless and algo != "onalgo":
+            raise ValueError("the sharded streaming engine rolls OnAlgo "
+                             "(plus the stateless local/cloud policies); "
+                             f"got {algo!r}")
+        if not stateless:
+            lam, mu, counts = _stream_state0(None, N, M, topo_k)
+            if source_cols is not None:  # shard-local slab generation
+                local_N = N // mesh.shape[device_axis]
+                L0 = min(slab, T)
+                has_overlay = jax.eval_shape(
+                    lambda t0, n0: source_cols(t0, L0, n0, local_N),
+                    jax.ShapeDtypeStruct((), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32))[1] is not None
+                bufs = (_stream_series_buffers(T, topology, has_overlay)
+                        if pipelined else None)
+    if stateless:
         return _stream_trivial(source, T, N, slab, tables, params, algo,
                                enforce_slot_capacity, topology=topology)
-    if algo != "onalgo":
-        raise ValueError("the sharded streaming engine rolls OnAlgo (plus "
-                         "the stateless local/cloud policies); got "
-                         f"{algo!r}")
-
-    lam = jnp.zeros((N,), jnp.float32)
-    mu = (jnp.float32(0.0) if topo_k is None
-          else jnp.zeros((topo_k.K,), jnp.float32))
-    counts = jnp.zeros((N, M), jnp.float32)
 
     def topo_args_at(t0, L):
         return (() if topo_k is None
@@ -1264,13 +1290,7 @@ def simulate_sharded_stream(source, T: int, N: int, tables,
         return off, j_slab, overlay, mu_seq, lnorm, lam, mu, counts
 
     parts = []
-    if source_cols is not None:  # shard-local slab generation
-        local_N = N // mesh.shape[device_axis]
-        L0 = min(slab, T)
-        has_overlay = jax.eval_shape(
-            lambda t0, n0: source_cols(t0, L0, n0, local_N),
-            jax.ShapeDtypeStruct((), jnp.int32),
-            jax.ShapeDtypeStruct((), jnp.int32))[1] is not None
+    if source_cols is not None:
         runs = {}  # one compiled run per distinct slab length
 
         def make_run(L):
@@ -1285,24 +1305,26 @@ def simulate_sharded_stream(source, T: int, N: int, tables,
                 has_overlay=has_overlay, topo=topo_static),
                 donate_argnums=(5, 6, 7))
 
-        bufs = (_stream_series_buffers(T, topology, has_overlay)
-                if pipelined else None)
         for t0 in range(0, T, slab):
-            L = min(slab, T - t0)
-            if L not in runs:
-                runs[L] = make_run(L)
-            out = runs[L](o_tab, h_tab, w_tab, params.B, params.H, lam,
-                          mu, counts, jnp.int32(t0), *topo_args_at(t0, L))
-            (off, j_slab, overlay, mu_seq, lnorm,
-             lam, mu, counts) = unpack(out, has_overlay)
-            if pipelined:
-                bufs = _stream_acct(bufs, off, j_slab, overlay, mu_seq,
-                                    lnorm, jnp.int32(t0), tables, params,
-                                    topology, enforce=enforce_slot_capacity)
-            else:
-                parts.append(_series_from_offloads(
-                    j_slab, off, tables, params, mu_seq, lnorm, overlay,
-                    enforce_slot_capacity, topology=topology, t0=t0))
+            with span(spans.STREAM_DISPATCH, t0=t0):
+                L = min(slab, T - t0)
+                if L not in runs:
+                    runs[L] = make_run(L)
+                out = runs[L](o_tab, h_tab, w_tab, params.B, params.H, lam,
+                              mu, counts, jnp.int32(t0),
+                              *topo_args_at(t0, L))
+                (off, j_slab, overlay, mu_seq, lnorm,
+                 lam, mu, counts) = unpack(out, has_overlay)
+                if pipelined:
+                    bufs = _stream_acct(
+                        bufs, off, j_slab, overlay, mu_seq, lnorm,
+                        jnp.int32(t0), tables, params, topology,
+                        enforce=enforce_slot_capacity)
+                else:
+                    parts.append(_series_from_offloads(
+                        j_slab, off, tables, params, mu_seq, lnorm,
+                        overlay, enforce_slot_capacity, topology=topology,
+                        t0=t0))
         final = onalgo.OnAlgoState(
             lam=lam, mu=mu,
             rho=onalgo.RhoEstimator(counts=counts, t=jnp.int32(T)))
@@ -1311,38 +1333,39 @@ def simulate_sharded_stream(source, T: int, N: int, tables,
     run = None
     bufs = None
     for t0 in range(0, T, slab):
-        L = min(slab, T - t0)
-        # Generation stays an eager per-slab call (service sources are
-        # themselves jitted slab launches) — dispatch is async, so the
-        # pipelined walk still never syncs inside the loop.
-        j_slab, overlay = source(t0, L)
-        if run is None:
-            # lam0/mu0/counts0 (args 6-8) are donated: the carry is dead
-            # once the next rollout returns.  Both walk modes share this
-            # construction so they run the exact same executable — the
-            # bit-identity contract rules out fusing the shard_map scan
-            # into a larger jit (see _stream_acct).
-            run = jax.jit(_make_sharded_run(
-                mesh, device_axis, rule,
-                per_device_tables=o_tab.ndim == 2,
-                has_overlay=overlay is not None, topo=topo_static),
-                donate_argnums=(6, 7, 8))
+        with span(spans.STREAM_DISPATCH, t0=t0):
+            L = min(slab, T - t0)
+            # Generation stays an eager per-slab call (service sources
+            # are themselves jitted slab launches) — dispatch is async, so
+            # the pipelined walk still never syncs inside the loop.
+            j_slab, overlay = source(t0, L)
+            if run is None:
+                # lam0/mu0/counts0 (args 6-8) are donated: the carry is
+                # dead once the next rollout returns.  Both walk modes
+                # share this construction so they run the exact same
+                # executable — the bit-identity contract rules out fusing
+                # the shard_map scan into a larger jit (see _stream_acct).
+                run = jax.jit(_make_sharded_run(
+                    mesh, device_axis, rule,
+                    per_device_tables=o_tab.ndim == 2,
+                    has_overlay=overlay is not None, topo=topo_static),
+                    donate_argnums=(6, 7, 8))
+                if pipelined:
+                    bufs = _stream_series_buffers(T, topology,
+                                                  overlay is not None)
+            ov_args = (() if overlay is None
+                       else (overlay.o, overlay.h, overlay.w))
+            off, mu_seq, lnorm, lam, mu, counts = run(
+                j_slab, o_tab, h_tab, w_tab, params.B, params.H, lam, mu,
+                counts, jnp.int32(t0), *ov_args, *topo_args_at(t0, L))
             if pipelined:
-                bufs = _stream_series_buffers(T, topology,
-                                              overlay is not None)
-        ov_args = (() if overlay is None
-                   else (overlay.o, overlay.h, overlay.w))
-        off, mu_seq, lnorm, lam, mu, counts = run(
-            j_slab, o_tab, h_tab, w_tab, params.B, params.H, lam, mu,
-            counts, jnp.int32(t0), *ov_args, *topo_args_at(t0, L))
-        if pipelined:
-            bufs = _stream_acct(bufs, off, j_slab, overlay, mu_seq, lnorm,
-                                jnp.int32(t0), tables, params, topology,
-                                enforce=enforce_slot_capacity)
-        else:
-            parts.append(_series_from_offloads(
-                j_slab, off, tables, params, mu_seq, lnorm, overlay,
-                enforce_slot_capacity, topology=topology, t0=t0))
+                bufs = _stream_acct(bufs, off, j_slab, overlay, mu_seq,
+                                    lnorm, jnp.int32(t0), tables, params,
+                                    topology, enforce=enforce_slot_capacity)
+            else:
+                parts.append(_series_from_offloads(
+                    j_slab, off, tables, params, mu_seq, lnorm, overlay,
+                    enforce_slot_capacity, topology=topology, t0=t0))
     final = onalgo.OnAlgoState(
         lam=lam, mu=mu,
         rho=onalgo.RhoEstimator(counts=counts, t=jnp.int32(T)))

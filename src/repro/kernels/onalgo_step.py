@@ -77,6 +77,7 @@ def onalgo_duals_pallas(lam, mu, rho, o_tab, h_tab, w_tab, B, *,
 
     gpow, load = pl.pallas_call(
         _onalgo_kernel,
+        name="onalgo_duals",
         grid=(n_tiles,),
         in_specs=[_SMEM, col, tile, tile, tile, tile, col],
         out_specs=[col, pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0))],
@@ -537,6 +538,7 @@ def onalgo_chunked_pallas(j_seq, lam0, mu0, counts0, o_tab, h_tab, w_tab,
     mu_in = m["mu_in"]
     outs = pl.pallas_call(
         kern,
+        name="onalgo_chunked",
         grid=(K,),
         input_output_aliases={mu_in - 1: 3, mu_in: 4, mu_in + 1: 5},
         in_specs=in_specs,
@@ -749,6 +751,7 @@ def onalgo_tiled_pallas(j_seq, lam0, mu0, counts0, o_tab, h_tab, w_tab,
                              topo_binned=m["topo_binned"])
     off, mu_seq, lnorm, mu_f, state_f = pl.pallas_call(
         kern,
+        name="onalgo_tiled",  # the trace's %onalgo_tiled: keep it stable
         grid=(K, chunk, n_tiles),
         # the HBM buffer the state tiles stream through starts out
         # holding the seed

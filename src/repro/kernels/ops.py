@@ -11,6 +11,8 @@ from functools import partial
 
 import jax
 
+from repro import spans
+
 
 def interpret_mode() -> bool:
     """True exactly when JAX's default platform is the CPU: the kernels
@@ -46,11 +48,11 @@ def onalgo_chunked(j_seq, lam0, mu0, counts0, o_tab, h_tab, w_tab, B, H,
     mu0 and the mu outputs are then (K,)-vectors.  ``topo_binned``
     selects the binned (hi, lo) topology reduction (None = auto by K)."""
     from repro.kernels.onalgo_step import onalgo_chunked_pallas
-    return onalgo_chunked_pallas(j_seq, lam0, mu0, counts0, o_tab, h_tab,
-                                 w_tab, B, H, a, beta, chunk=chunk, t0=t0,
-                                 slot_values=slot_values, assoc=assoc,
-                                 H_k=H_k, topo_binned=topo_binned,
-                                 interpret=interpret_mode())
+    with jax.named_scope(spans.ROLLOUT):
+        return onalgo_chunked_pallas(
+            j_seq, lam0, mu0, counts0, o_tab, h_tab, w_tab, B, H, a, beta,
+            chunk=chunk, t0=t0, slot_values=slot_values, assoc=assoc,
+            H_k=H_k, topo_binned=topo_binned, interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("chunk", "block_n", "topo_binned"))
@@ -60,12 +62,12 @@ def onalgo_tiled(j_seq, lam0, mu0, counts0, o_tab, h_tab, w_tab, B, H,
     """Device-tiled fused rollout (see onalgo_step.onalgo_tiled_pallas):
     same results as ``onalgo_chunked`` with O(block_n * M) VMEM."""
     from repro.kernels.onalgo_step import onalgo_tiled_pallas
-    return onalgo_tiled_pallas(j_seq, lam0, mu0, counts0, o_tab, h_tab,
-                               w_tab, B, H, a, beta, chunk=chunk,
-                               block_n=block_n, t0=t0,
-                               slot_values=slot_values, assoc=assoc,
-                               H_k=H_k, topo_binned=topo_binned,
-                               interpret=interpret_mode())
+    with jax.named_scope(spans.ROLLOUT):
+        return onalgo_tiled_pallas(
+            j_seq, lam0, mu0, counts0, o_tab, h_tab, w_tab, B, H, a, beta,
+            chunk=chunk, block_n=block_n, t0=t0, slot_values=slot_values,
+            assoc=assoc, H_k=H_k, topo_binned=topo_binned,
+            interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))

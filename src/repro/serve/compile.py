@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.fleet import RawOverlay, Trace
 from repro.core.onalgo import OnAlgoParams, StepRule, risk_adjusted_gain
 from repro.core.state_space import StateSpace
@@ -87,14 +88,15 @@ def _lower_values(wl, space, on_override, o_levels, cycles, phi_hat,
     channel streams are unaffected (counter addressing has no
     draw-order coupling).
     """
-    on = wl.on if on_override is None else on_override
-    o_raw = o_levels[wl.rates]
-    h_raw = cycles[wl.img]
-    w_raw = risk_adjusted_gain(phi_hat[wl.img], sigma[wl.img], v_risk)
-    w_raw = jnp.clip(w_raw - zeta_pen, 0.0, 1.0)
-    j = quantize_states_device(space, o_raw, h_raw, w_raw, on)
-    return (on, j, o_raw, h_raw, w_raw, corr_local[wl.img],
-            corr_cloud[wl.img], d_local[wl.img])
+    with jax.named_scope(spans.VALUE_LOWERING):
+        on = wl.on if on_override is None else on_override
+        o_raw = o_levels[wl.rates]
+        h_raw = cycles[wl.img]
+        w_raw = risk_adjusted_gain(phi_hat[wl.img], sigma[wl.img], v_risk)
+        w_raw = jnp.clip(w_raw - zeta_pen, 0.0, 1.0)
+        j = quantize_states_device(space, o_raw, h_raw, w_raw, on)
+        return (on, j, o_raw, h_raw, w_raw, corr_local[wl.img],
+                corr_cloud[wl.img], d_local[wl.img])
 
 
 @partial(jax.jit,

@@ -36,6 +36,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.workload import streams
 from repro.workload.service import ServiceWorkload, arrival_chain_probs
 
@@ -112,13 +113,15 @@ class StreamingWorkload:
             nb = (length - 1) // RB + 2  # covers any offset within a block
             b0 = t0 // RB
             off = t0 - b0 * RB
-        u = streams.uniform_block_range(self.seed, streams.STREAM_SERVICE,
-                                        b0, nb, self.N, 4)
-        on_in = jax.lax.dynamic_index_in_dim(self.on_entry, b0,
-                                             keepdims=False)
-        rate_in = jax.lax.dynamic_index_in_dim(self.rate_entry, b0,
-                                               keepdims=False)
-        return self._finish_slab(u, on_in, rate_in, b0, nb, off, length)
+        with jax.named_scope(spans.WORKLOAD_GEN):
+            u = streams.uniform_block_range(
+                self.seed, streams.STREAM_SERVICE, b0, nb, self.N, 4)
+            on_in = jax.lax.dynamic_index_in_dim(self.on_entry, b0,
+                                                 keepdims=False)
+            rate_in = jax.lax.dynamic_index_in_dim(self.rate_entry, b0,
+                                                   keepdims=False)
+            return self._finish_slab(u, on_in, rate_in, b0, nb, off,
+                                     length)
 
     def slab_cols(self, t0, length: int, n0, n_cols: int, *,
                   aligned: bool = False) -> ServiceWorkload:
@@ -142,16 +145,18 @@ class StreamingWorkload:
             nb = (length - 1) // RB + 2
             b0 = t0 // RB
             off = t0 - b0 * RB
-        u = streams.uniform_block_range(self.seed, streams.STREAM_SERVICE,
-                                        b0, nb, self.N, 4, n0=n0,
-                                        n_cols=n_cols)
         cols = lambda x: jax.lax.dynamic_slice_in_dim(x, n0, n_cols,
                                                       axis=-1)
-        on_in = cols(jax.lax.dynamic_index_in_dim(self.on_entry, b0,
-                                                  keepdims=False))
-        rate_in = cols(jax.lax.dynamic_index_in_dim(self.rate_entry, b0,
-                                                    keepdims=False))
-        return self._finish_slab(u, on_in, rate_in, b0, nb, off, length)
+        with jax.named_scope(spans.WORKLOAD_GEN):
+            u = streams.uniform_block_range(
+                self.seed, streams.STREAM_SERVICE, b0, nb, self.N, 4,
+                n0=n0, n_cols=n_cols)
+            on_in = cols(jax.lax.dynamic_index_in_dim(self.on_entry, b0,
+                                                      keepdims=False))
+            rate_in = cols(jax.lax.dynamic_index_in_dim(
+                self.rate_entry, b0, keepdims=False))
+            return self._finish_slab(u, on_in, rate_in, b0, nb, off,
+                                     length)
 
 
 @partial(jax.jit,
