@@ -106,8 +106,9 @@ def risk_adjusted_gain(phi_hat, sigma, v_risk):
     lowering (``serve.compile._lower_values``) and every
     :mod:`repro.gain` source (table / overlay / model) route through this
     function, so a gain estimate pre-folded into a table is bit-identical
-    to the same expression fused into the per-slot gather path.
-    Elementwise float ops only: commutes exactly with gathers.
+    to the same expression applied to each looked-up element (the
+    lowering itself folds it per table entry).  Elementwise float ops
+    only: commutes exactly with a lookup.
     """
     return jnp.clip(phi_hat - v_risk * sigma, 0.0, 1.0)
 

@@ -22,7 +22,7 @@ Three sources:
     adjustment is pre-folded into a single raw gain table
     (``w = clip(phi - v*sigma, 0, 1)``, sigma = 0 downstream).  Because
     :func:`~repro.core.onalgo.risk_adjusted_gain` is elementwise it
-    commutes exactly with the per-slot image gather — the overlay ``w``
+    commutes exactly with the per-slot image lookup — the overlay ``w``
     stream is bit-identical to the table source's on every engine.
 
   :class:`ModelGain` — a trained predictor (closed-form ridge or a tiny
@@ -121,7 +121,7 @@ class OverlayGain(GainSource):
 
     The same float32 ops :func:`risk_adjusted_gain` applies inside the
     fused lowering are applied to the whole (S,) table up front;
-    elementwise ops commute exactly with the per-slot gather, and
+    elementwise ops commute exactly with the per-slot lookup, and
     ``w - v*0`` then ``clip`` are bitwise identities on values already
     in [0, 1] — so the overlay's raw ``w`` stream, and therefore every
     decision, is bit-identical to the table source.  The state space

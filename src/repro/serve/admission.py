@@ -23,24 +23,28 @@ from repro.core.onalgo import OnAlgoParams, StepRule
 from repro.core.state_space import StateSpace
 
 
+def nearest_level(x, levels) -> jax.Array:
+    """Index of the level nearest each value of ``x``: float32 distances,
+    ties to the first level.  Elementwise in ``x``, so it commutes exactly
+    with a lookup (the service lowering quantizes its (S,) tables with
+    it, then looks the indices up)."""
+    lv = jnp.asarray(levels, jnp.float32)
+    return jnp.argmin(jnp.abs(jnp.asarray(x, jnp.float32)[..., None] - lv),
+                      axis=-1)
+
+
 @partial(jax.jit, static_argnames=("space",))
 def quantize_states_device(space: StateSpace, o, h, w, task_mask
                            ) -> jax.Array:
     """Device-side :func:`quantize_states`: one fused jitted pass from raw
-    (o, h, w, task) to int32 state indices, jit-composable (the compiled
-    service uses it inside its single compile kernel).  ``space`` is
+    (o, h, w, task) to int32 state indices, jit-composable.  ``space`` is
     static (frozen/hashable), so the level grids fold into the program as
     constants; ``StateSpace.encode`` stays the single source of truth for
     the state layout.  Nearest-level ties break to the first level, in
-    float32 distances."""
-    def nearest(x, levels):
-        lv = jnp.asarray(levels, jnp.float32)
-        return jnp.argmin(jnp.abs(jnp.asarray(x, jnp.float32)[..., None]
-                                  - lv), axis=-1)
-
-    io = nearest(o, space.o_levels)
-    ih = nearest(h, space.h_levels)
-    iw = nearest(w, space.w_levels)
+    float32 distances (:func:`nearest_level`)."""
+    io = nearest_level(o, space.o_levels)
+    ih = nearest_level(h, space.h_levels)
+    iw = nearest_level(w, space.w_levels)
     j = space.encode(io, ih, iw).astype(jnp.int32)
     return jnp.where(jnp.asarray(task_mask, bool), j, jnp.int32(0))
 

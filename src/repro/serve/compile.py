@@ -40,7 +40,7 @@ from repro import spans
 from repro.core.fleet import RawOverlay, Trace
 from repro.core.onalgo import OnAlgoParams, StepRule, risk_adjusted_gain
 from repro.core.state_space import StateSpace
-from repro.serve.admission import quantize_states_device
+from repro.serve.admission import nearest_level
 from repro.workload import (StreamingWorkload, generate_service_workload,
                             lower_service_workload, validate_rng_version)
 
@@ -74,12 +74,64 @@ class CompiledService:
         return self.trace, self.tables, self.params
 
 
+# Tables of at most this many entries (one lane width) are looked up by a
+# select tree; longer ones keep XLA's gather (``lookup``).
+SELECT_LOOKUP_MAX = 128
+
+
+def lookup(idx, tables):
+    """``tuple(t[idx] for t in tables)`` for (S,) tables that share one
+    integer index array, bit for bit.
+
+    For S <= ``SELECT_LOOKUP_MAX`` a binary select tree on the index's
+    bits: each table's entries are paired level by level, one ``where``
+    per pair on the level's bit, S - 1 selects in all; the bit tests
+    are shared by every table.  The ops are elementwise over ``idx``,
+    so XLA fuses them into lane-dense loops (into the consumers, mostly)
+    and never materializes an (..., S) one-hot; a select keeps every bit
+    (``-0.0`` too, which a one-hot sum would not).  XLA's TPU gather
+    issues one element per index, about a thousand times below HBM
+    bandwidth on tables this small.  (A chain of ``idx == s`` selects
+    gives the same bits, but XLA materializes its S shared compares as
+    (L, N) masks.)  The index is first clamped to S - 1, so an index at
+    or above S - 1 reads that entry as the gather's clamp would; every
+    producer (``levels_from_uniform``) emits indices in [0, S).
+
+    The tree costs O(S) an element and the gather does not grow with S,
+    so a longer table (a pool built from a real test set) keeps the
+    gather; the choice is fixed by the static table length.
+    """
+    S = tables[0].shape[0]
+    if S > SELECT_LOOKUP_MAX:
+        return tuple(t[idx] for t in tables)
+    idx = jnp.minimum(idx, S - 1)
+    bits = [((idx >> b) & 1) == 1 for b in range((S - 1).bit_length())]
+    outs = []
+    for t in tables:
+        level = [t[s] for s in range(S)]
+        for bit in bits:
+            # a lone last entry has this bit clear for every index reaching it
+            level = [jnp.where(bit, level[i + 1], level[i])
+                     if i + 1 < len(level) else level[i]
+                     for i in range(0, len(level), 2)]
+        outs.append(jnp.broadcast_to(level[0], idx.shape))
+    return tuple(outs)
+
+
 def _lower_values(wl, space, on_override, o_levels, cycles, phi_hat,
                   sigma, d_local, corr_local, corr_cloud, v_risk,
                   zeta_pen):
-    """Raw-value gathers + quantization for a realized workload (whole
+    """Raw-value lookups + quantization for a realized workload (whole
     horizon or slab) — the one definition both the materialized and the
     streaming lowerings go through, so their outputs are bit-identical.
+
+    Every step applied to a looked-up value is elementwise, and float32
+    elementwise ops commute exactly with a lookup, so the gain, the
+    ``zeta_pen`` clip and the nearest-level quantization are folded
+    into the (S,) pool and (num_rates,) rate tables first; the (L, N)
+    slab then only looks values up (``lookup``).  The state index
+    splits by ``StateSpace.encode``'s affine layout into a rate part
+    and an image part, joined by an exact integer add.
 
     Returns (on, j_idx, o, h, w, correct_local, correct_cloud, d_local).
     ``zeta_pen`` is the P3 delay penalty (0 disables it exactly:
@@ -90,13 +142,22 @@ def _lower_values(wl, space, on_override, o_levels, cycles, phi_hat,
     """
     with jax.named_scope(spans.VALUE_LOWERING):
         on = wl.on if on_override is None else on_override
-        o_raw = o_levels[wl.rates]
-        h_raw = cycles[wl.img]
-        w_raw = risk_adjusted_gain(phi_hat[wl.img], sigma[wl.img], v_risk)
-        w_raw = jnp.clip(w_raw - zeta_pen, 0.0, 1.0)
-        j = quantize_states_device(space, o_raw, h_raw, w_raw, on)
-        return (on, j, o_raw, h_raw, w_raw, corr_local[wl.img],
-                corr_cloud[wl.img], d_local[wl.img])
+        w_tab = risk_adjusted_gain(phi_hat, sigma, v_risk)
+        w_tab = jnp.clip(w_tab - zeta_pen, 0.0, 1.0)
+        io = nearest_level(o_levels, space.o_levels)
+        ih = nearest_level(cycles, space.h_levels)
+        iw = nearest_level(w_tab, space.w_levels)
+        # encode(io, ih, iw) == encode(io, 0, 0) + encode(0, ih, iw)
+        #                       - encode(0, 0, 0)
+        jo_tab = space.encode(io, 0, 0).astype(jnp.int32)
+        jhw_tab = (space.encode(0, ih, iw)
+                   - space.encode(0, 0, 0)).astype(jnp.int32)
+        o_raw, jo = lookup(wl.rates, (o_levels, jo_tab))
+        h_raw, w_raw, c_local, c_cloud, d_loc, jhw = lookup(
+            wl.img, (cycles, w_tab, corr_local, corr_cloud, d_local,
+                     jhw_tab))
+        j = jnp.where(jnp.asarray(on, bool), jo + jhw, jnp.int32(0))
+        return on, j, o_raw, h_raw, w_raw, c_local, c_cloud, d_loc
 
 
 @partial(jax.jit,
@@ -106,7 +167,7 @@ def _compile_v1(seed, T, N, pool_size, num_rates, burst_len, mean_gap,
                 space, on_override, o_levels, cycles, phi_hat, sigma,
                 d_local, corr_local, corr_cloud, v_risk, zeta_pen):
     """The whole v1 lowering as ONE fused device pass: counter-based
-    workload generation, raw-value gathers, and state quantization."""
+    workload generation, raw-value lookups, and state quantization."""
     wl = generate_service_workload(seed, T, N, pool_size, num_rates,
                                    burst_len, mean_gap)
     return _lower_values(wl, space, on_override, o_levels, cycles, phi_hat,
@@ -178,7 +239,7 @@ def compile_service(sim, pool, on: Optional[np.ndarray] = None, *,
                     gain_source=None) -> CompiledService:
     """Lower (SimConfig, PrecomputedPool) to a :class:`CompiledService`.
 
-    Workload generation, value gathers, and quantization run as one
+    Workload generation, value lookups, and quantization run as one
     fused jitted device pass over counter-based streams (RNG contract
     v1, the only live one) — no per-slot host loop anywhere.
 
@@ -225,7 +286,8 @@ def _service_slab(wl: StreamingWorkload, space, t0, length, o_levels,
                   cycles, phi_hat, sigma, d_local, corr_local, corr_cloud,
                   v_risk, zeta_pen, aligned: bool = False):
     """One fused device pass from counters to a service slab: workload
-    slab -> gathers -> quantization, slots [t0, t0 + length).
+    slab -> per-entry fold and quantization -> lookups, slots
+    [t0, t0 + length).
 
     ``aligned`` promises ``t0 % ROW_BLOCK == 0`` and generates one fewer
     covering uniform block per slab (see ``StreamingWorkload.slab``)."""
@@ -313,7 +375,7 @@ def compile_service_streaming(sim, pool, *,
     overrides need the materialized path — use ``compile_service``.
     ``gain_source`` as in :func:`compile_service`: the resolved (S,)
     tables ride in ``arrays``, so every slab — full-width, aligned, or
-    column-addressed — gathers from the same source.
+    column-addressed — looks up the same source.
     """
     space, arrays, params, knobs, num_rates = _service_inputs(
         sim, pool, gain_source)

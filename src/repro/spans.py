@@ -14,7 +14,7 @@ the operation's event metadata (a fusion carries its root's).
 
 # device scopes, one per layer of the fused slab step
 WORKLOAD_GEN = "repro.workload_gen"  # counter draws, chains, hold-resample
-VALUE_LOWERING = "repro.value_lowering"  # pool gathers, gain, quantization
+VALUE_LOWERING = "repro.value_lowering"  # pool lookups, gain, quantization
 ROLLOUT = "repro.rollout"  # the Pallas rollout kernel and its operands
 ADMISSION = "repro.admission"  # per-slot cloudlet capacity admission
 ACCOUNTING = "repro.accounting"  # per-slot series and their buffers

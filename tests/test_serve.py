@@ -417,6 +417,83 @@ class TestServiceWorkloads:
         np.testing.assert_array_equal(
             quantize_states(space, o[0], h[0], w[0], task[0]), want[0])
 
+    @pytest.mark.parametrize("shape", [(64, 300), (3, 1)])
+    @pytest.mark.parametrize("S", [1, 3, 64, 128, 129, 512])
+    def test_lookup_equals_gather_bit_for_bit(self, S, shape):
+        """The value lowering folds each (S,) table per entry and looks
+        it up (a select tree for S <= 128, the gather above): its
+        outputs equal gathering the raw tables with ``jnp.take`` and
+        folding each element, as bits, on tables holding -0.0,
+        subnormals and values at a level midpoint; and ``j`` equals
+        ``quantize_states_device`` of the gathered values for every
+        index in [0, S)."""
+        import types
+
+        from repro.core.onalgo import risk_adjusted_gain
+        from repro.serve.admission import quantize_states_device
+        from repro.serve.compile import _lower_values
+        from repro.serve.simulator import RATES, power_of_rate
+
+        space = StateSpace(o_levels=tuple(power_of_rate(RATES).tolist()),
+                           h_levels=(351e6, 441e6, 531e6),
+                           w_levels=tuple(np.linspace(0, 0.3, 8).tolist()))
+        rng = np.random.default_rng(S)
+        f32 = np.float32
+        specials = np.array([-0.0, 1e-40, -1e-40, 0.0], f32)
+
+        def table(lo, hi, mids=()):
+            t = rng.uniform(lo, hi, S).astype(f32)
+            extra = np.concatenate([specials, np.asarray(mids, f32)])
+            at = rng.permutation(S)[:min(S, len(extra))]
+            t[at] = extra[:len(at)]
+            return t
+
+        w_lv = np.asarray(space.w_levels, f32)
+        h_lv = np.asarray(space.h_levels, f32)
+        o_lv = np.asarray(space.o_levels, f32)
+        mid = lambda lv: (lv[:-1] + lv[1:]) / f32(2)
+        v_risk, zeta_pen = f32(0.5), f32(2.0**-7)
+        cycles = table(150e6, 700e6, mid(h_lv))
+        # phi = midpoint + zeta_pen with sigma = 0 folds onto the midpoint
+        phi = table(0.0, 0.4, mid(w_lv) + zeta_pen)
+        sigma = table(0.0, 0.1)
+        sigma[np.isin(phi, mid(w_lv) + zeta_pen)] = 0.0
+        tabs = tuple(jnp.asarray(t) for t in (
+            cycles, phi, sigma, table(0.3, 1.0), table(0.0, 1.0),
+            table(0.0, 1.0)))  # cycles, phi, sigma, d_local, cl, cc
+        o_levels = jnp.asarray([o_lv[0], mid(o_lv)[1], o_lv[2]], f32)
+
+        def lowered(img, rates, on):
+            wl = types.SimpleNamespace(img=img, rates=rates, on=on)
+            return _lower_values(wl, space, None, o_levels, tabs[0],
+                                 tabs[1], tabs[2], tabs[3], tabs[4],
+                                 tabs[5], v_risk, zeta_pen)
+
+        def per_element(img, rates, on):
+            cyc, ph, sg, dl, cl, cc = (jnp.take(t, img) for t in tabs)
+            o = jnp.take(o_levels, rates)
+            w = jnp.clip(risk_adjusted_gain(ph, sg, v_risk) - zeta_pen,
+                         0.0, 1.0)
+            j = quantize_states_device(space, o, cyc, w, on)
+            return on, j, o, cyc, w, cl, cc, dl
+
+        img = rng.integers(0, S, shape).astype(np.int32)
+        rates = rng.integers(0, len(RATES), shape).astype(np.int32)
+        on = rng.random(shape) < 0.6
+        every = np.arange(S, dtype=np.int32)[None]
+        for args in ((img, rates, on),
+                     (every, every % len(RATES), np.ones_like(every, bool))):
+            got = jax.jit(lowered)(*args)
+            want = jax.jit(per_element)(*args)
+            for name, g, w in zip(("on", "j", "o", "h", "w", "cl", "cc",
+                                   "d_local"), got, want):
+                g, w = np.asarray(g), np.asarray(w)
+                assert g.dtype == w.dtype and g.shape == w.shape, name
+                np.testing.assert_array_equal(
+                    g.view(np.uint32) if g.dtype == np.float32 else g,
+                    w.view(np.uint32) if w.dtype == np.float32 else w,
+                    err_msg=name)
+
 
 @pytest.mark.slow
 class TestSimulator:

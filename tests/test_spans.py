@@ -50,6 +50,32 @@ def test_slab_step_ops_carry_scope(slab_step_text, scope):
     assert re.search(f'op_name="[^"]*/{re.escape(scope)}/', slab_step_text)
 
 
+def _gathers_under(text, scope):
+    """Gather ops in compiled HLO text whose ``op_name`` carries ``scope``."""
+    return [line for line in text.splitlines()
+            if re.search(r"= \S+ gather\(", line)
+            and re.search(f'op_name="[^"]*/{re.escape(scope)}/', line)]
+
+
+def test_slab_step_value_lowering_selects_small_tables(slab_step_text):
+    """With the 64-image pool the value lowering looks its tables up by
+    select: no gather in the compiled slab step carries its scope."""
+    assert not _gathers_under(slab_step_text, spans.VALUE_LOWERING)
+
+
+def test_service_slab_value_lowering_gathers_large_tables():
+    """A pool of more than 128 images keeps the gather: the path is
+    fixed per compile by the table's static length."""
+    from repro.serve.compile import _service_slab
+
+    sim = SimConfig(num_devices=N, T=128, algo="onalgo", B_n=0.06,
+                    H=N * 441e6 / 2, seed=11)
+    ss = compile_service_streaming(sim, synthetic_pool(S=512))
+    text = _service_slab.lower(ss.wl, ss.space, 0, 64, *ss.arrays,
+                               *ss.knobs).compile().as_text()
+    assert _gathers_under(text, spans.VALUE_LOWERING)
+
+
 def _f32(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.float32)
 
